@@ -88,7 +88,7 @@ def _fused_rows(smoke: bool) -> list[Row]:
             lambda m: gather_segment_sum_ref(m, srcj, dstj, maskj, N)
         )
         fused_fn = jax.jit(
-            lambda m: gather_segment_sum(m, srcj, pp, pd, N)
+            lambda m: gather_segment_sum(m, srcj, pp, pd, N, interpret=True)
         )
         t_jnp = timeit(lambda: jax.block_until_ready(jnp_fn(mixed)), iters=2)
         t_fus = timeit(lambda: jax.block_until_ready(fused_fn(mixed)), iters=2)
@@ -119,7 +119,8 @@ def _fused_rows(smoke: bool) -> list[Row]:
     jnp_fn = jax.jit(
         lambda m: gather_weighted_segsum_ref(m, w, srcj, dstj, maskj, N)
     )
-    fused_fn = jax.jit(lambda m: gather_weighted_segsum(m, w, srcj, pp, pd, N))
+    fused_fn = jax.jit(lambda m: gather_weighted_segsum(m, w, srcj, pp, pd, N,
+                                                       interpret=True))
     t_jnp = timeit(lambda: jax.block_until_ready(jnp_fn(mixed)), iters=2)
     t_fus = timeit(lambda: jax.block_until_ready(fused_fn(mixed)), iters=2)
     out_j, out_f = np.asarray(jnp_fn(mixed)), np.asarray(fused_fn(mixed))
@@ -147,9 +148,9 @@ def _legacy_rows(smoke: bool) -> list[Row]:
         )
     )
     t_pal = timeit(
-        lambda: jax.block_until_ready(segment_sum_pallas(contrib, dst, mask, N))
+        lambda: jax.block_until_ready(segment_sum_pallas(contrib, dst, mask, N, interpret=True))
     )
-    out = np.asarray(segment_sum_pallas(contrib, dst, mask, N))
+    out = np.asarray(segment_sum_pallas(contrib, dst, mask, N, interpret=True))
     if not np.isfinite(out).all():
         raise SystemExit("NaN/Inf in segsum bench output")
     flops = 2 * E * F  # one MAC per (edge, feature)
@@ -166,9 +167,9 @@ def _legacy_rows(smoke: bool) -> list[Row]:
         )
     )
     t_pal = timeit(
-        lambda: jax.block_until_ready(edge_softmax_pallas(logits, dst, mask, N))
+        lambda: jax.block_until_ready(edge_softmax_pallas(logits, dst, mask, N, interpret=True))
     )
-    out = np.asarray(edge_softmax_pallas(logits, dst, mask, N))
+    out = np.asarray(edge_softmax_pallas(logits, dst, mask, N, interpret=True))
     if not np.isfinite(out).all():
         raise SystemExit("NaN/Inf in edge_softmax bench output")
     rows.append(Row("kernel/edge_softmax/jnp", t_ref * 1e6, f"E={E} H={H}"))

@@ -84,6 +84,9 @@ BENCHES = {
 def main() -> None:
     import importlib
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     names = [a for a in sys.argv[1:] if a in BENCHES] or list(BENCHES)
     print("name,us_per_call,derived")
     failures = []

@@ -20,7 +20,7 @@ interpret-mode Pallas wall time is not TPU time in ``kernel_bench``: these
 rows track the ratio and the fallback counts so regressions are visible;
 the placement win (sampling runs where the frontier lives, no host
 round-trip per batch) is an accelerator claim, measured by rerunning this
-file there with ``backend="pallas", interpret=False``. A
+file there, where the ``pallas`` backend compiles its kernel. A
 ``pallas_interpret`` row is included once for visibility. Steady state must
 be fallback-free for the device path to matter on any backend.
 
@@ -110,13 +110,13 @@ def _bench_rows(dataset: str, sweep) -> list[Row]:
             f"host_over_device={t_ph / t_pd:.2f}",
         ))
 
-    # one interpret-mode Pallas point for visibility (wall time is the
+    # one Pallas point for visibility (on the CPU its wall time is the
     # interpreter, not a TPU — see module docstring)
     batch, fanouts = sweep[0]
     host, part, _ = _setup(ds, fanouts, batch)
     engp = DeviceSampler(
         ds.graph, part.assignment, NUM_DEVICES, list(fanouts), 0, host,
-        backend="pallas", interpret=True,
+        backend="pallas",
     )
     targets = host.epoch_targets(0)[0]
     t_p = timeit(lambda: engp.sample_batch(targets, 0, 0), iters=2)
@@ -145,7 +145,7 @@ def _invariant_gate(dataset: str = "tiny") -> list[Row]:
     )
     engp = DeviceSampler(
         ds.graph, part.assignment, NUM_DEVICES, list(fanouts), 0, host,
-        backend="pallas", interpret=True,
+        backend="pallas",
     )
     deg = np.diff(ds.graph.indptr)
     owner = eng.shards.owner

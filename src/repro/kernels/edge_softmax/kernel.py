@@ -65,7 +65,7 @@ def edge_softmax_packed(
     rows: int = 128,
     edge_block: int = 512,
     head_block: int = 8,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     total, H = logits_packed.shape
     EB = edge_block

@@ -14,7 +14,8 @@ def edge_softmax_pallas(
     mask,  # (E,) concrete
     num_out: int,
     rows: int = 128,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Per-destination softmax over incoming edges: (E, H) -> (E, H).
 
@@ -32,7 +33,8 @@ def edge_softmax_from_pack(
     logits: jnp.ndarray,
     pack: dict,
     head_block: int = 8,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jnp.ndarray:
     E, H = logits.shape
     Hp = ((H + head_block - 1) // head_block) * head_block

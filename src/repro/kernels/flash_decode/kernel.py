@@ -65,7 +65,7 @@ def decode_attention_pallas_bkv(
     cache_len: jnp.ndarray,  # (BKV, 1) int32
     *,
     seq_block: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     BKV, G, D = q.shape
     S = k.shape[1]

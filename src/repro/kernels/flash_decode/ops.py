@@ -12,7 +12,8 @@ def decode_attention_pallas(
     v: jnp.ndarray,  # (B, S, KV, Dv)
     cache_len,  # scalar int32
     seq_block: int = 512,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Single-token GQA decode attention -> (B, H, Dv).
 

@@ -199,7 +199,7 @@ def gather_segsum_fwd(
     feat_block: int = 128,
     head_dim: int = 0,  # dh (weighted only)
     acc_dtype=jnp.float32,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Fused forward: (DB*R, Fp) per-destination sums in ``acc_dtype``."""
     Mp, Fp = mixed.shape
@@ -250,7 +250,7 @@ def gather_segsum_bwd_mixed(
     feat_block: int = 128,
     head_dim: int = 0,
     acc_dtype=jnp.float32,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Adjoint w.r.t. ``mixed``: (Mp, Fp) — same layout, roles swapped."""
     _, Fp = g.shape
@@ -303,7 +303,7 @@ def gather_segsum_bwd_w(
     feat_block: int = 128,
     head_dim: int,
     acc_dtype=jnp.float32,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Adjoint w.r.t. the per-slot weights: (DB*EB, H)."""
     Mp, Fp = mixed.shape

@@ -154,7 +154,7 @@ def gather_segment_sum(
     rows: int = AGG_ROWS,
     mem_block: int = 128,
     feat_block: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Fused ``segment_sum(mixed[edge_src], dst)`` -> (num_out, F).
 
@@ -188,7 +188,7 @@ def gather_segment_mean(
     rows: int = AGG_ROWS,
     mem_block: int = 128,
     feat_block: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Fused masked segment mean -> (num_out, F).
 
@@ -216,7 +216,7 @@ def gather_weighted_segsum(
     rows: int = AGG_ROWS,
     mem_block: int = 128,
     feat_block: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Fused ``segment_sum(weights[e, h] * mixed[src, h*dh:(h+1)*dh], dst)``.
 

@@ -23,6 +23,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import pallas_interpret
+
 
 def segment_sum(contrib, dst, mask, num_out, backend="jnp"):
     """Masked per-destination sum of ``contrib (E, F)`` -> ``(num_out, F)``.
@@ -33,7 +35,9 @@ def segment_sum(contrib, dst, mask, num_out, backend="jnp"):
     if backend == "pallas":
         from repro.kernels.segsum import ops as segsum_ops
 
-        return segsum_ops.segment_sum_pallas(contrib, dst, mask, num_out)
+        return segsum_ops.segment_sum_pallas(
+            contrib, dst, mask, num_out, interpret=pallas_interpret()
+        )
     w = mask.astype(contrib.dtype)
     return jax.ops.segment_sum(contrib * w[:, None], dst, num_segments=num_out)
 
@@ -65,7 +69,9 @@ def edge_softmax(logits, dst, mask, num_out, backend="jnp"):
     if backend == "pallas":
         from repro.kernels.edge_softmax import ops as es_ops
 
-        return es_ops.edge_softmax_pallas(logits, dst, mask, num_out)
+        return es_ops.edge_softmax_pallas(
+            logits, dst, mask, num_out, interpret=pallas_interpret()
+        )
     neg = jnp.asarray(jnp.finfo(logits.dtype).min / 2, logits.dtype)
     masked = jnp.where(mask[:, None], logits, neg)
     seg_max = jax.ops.segment_max(masked, dst, num_segments=num_out)

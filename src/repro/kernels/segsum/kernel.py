@@ -48,7 +48,7 @@ def segment_sum_packed(
     rows: int = 128,  # R: dst rows per block
     edge_block: int = 512,  # EB
     feat_block: int = 128,  # FB
-    interpret: bool = True,  # CPU container: interpret mode; False on TPU
+    interpret: bool,
 ) -> jnp.ndarray:
     total, F = contrib_packed.shape
     EB = edge_block

@@ -65,7 +65,8 @@ def segment_sum_pallas(
     num_out: int,
     rows: int = 128,
     feat_block: int = 128,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Masked ``segment_sum(contrib, dst)`` -> (num_out, F) via the packed
     Pallas kernel.
@@ -88,7 +89,8 @@ def segment_sum_from_pack(
     pack: dict,
     num_out: int,
     feat_block: int = 128,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Device-side: gather into packed order, run the kernel, unpad."""
     E, F = contrib.shape

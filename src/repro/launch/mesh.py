@@ -7,12 +7,15 @@ across pods/hosts, model ("split") parallelism within.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    # Auto axes: shardings come from jit in/out_shardings and in-model
+    # PartitionSpec hints, not from Explicit-axis array types
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def data_axes(mesh) -> tuple[str, ...]:

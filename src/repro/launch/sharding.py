@@ -9,7 +9,7 @@ replicated — a documented cost, revisited in EXPERIMENTS.md §Perf.
 from __future__ import annotations
 
 import jax
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 from repro.models.transformer.config import ArchConfig
 
@@ -165,6 +165,11 @@ def make_split_mesh(
     stay on the fast intra-group links while only the once-per-step
     gradient psum crosses the ``replica_axis`` (DESIGN.md §9). ``R == 1``
     degenerates to the 1D split mesh (the equivalence tests' anchor).
+
+    Both axes are ``Auto``: the split path places every array through
+    ``shard_map`` specs, and an ``Explicit`` axis (``jax.make_mesh``'s
+    default) would demand a ``jax.set_mesh`` context around every jit and
+    grad that touches the mesh.
     """
     if num_replicas < 1 or num_splits < 1:
         raise ValueError(
@@ -172,7 +177,8 @@ def make_split_mesh(
         )
     kwargs = {} if devices is None else {"devices": devices}
     return jax.make_mesh(
-        (num_replicas, num_splits), (replica_axis, split_axis), **kwargs
+        (num_replicas, num_splits), (replica_axis, split_axis),
+        axis_types=(AxisType.Auto, AxisType.Auto), **kwargs
     )
 
 

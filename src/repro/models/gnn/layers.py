@@ -15,6 +15,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec
 
 from repro.core.shuffle import (
     SimComm,
@@ -23,7 +24,7 @@ from repro.core.shuffle import (
     sim_append_replicated,
     spmd_append_replicated,
 )
-from repro.kernels import segment_ops
+from repro.kernels import pallas_interpret, segment_ops
 from repro.kernels.gather_segsum import ops as gather_ops
 
 
@@ -39,7 +40,6 @@ class GNNSpec:
     # per-edge buffer + XLA scatter-add; "pallas" runs the fused
     # gather->segment-aggregate kernels over the plan's dst-sorted layout.
     agg_backend: str = "jnp"  # jnp | pallas
-    agg_interpret: bool = True  # pallas: interpret mode (CPU); False on TPU
     # Overlap-aware shuffle schedule (DESIGN.md §3a). ``overlap`` switches
     # the per-layer step from blocking shuffle->aggregate to split
     # aggregation: the local-src half is aggregated from the device's own
@@ -117,7 +117,7 @@ def _agg_mean(spec: GNNSpec, mixed: jnp.ndarray, lp: dict, num_out: int):
     if spec.agg_backend == "pallas":
         return gather_ops.gather_segment_mean(
             mixed, lp["edge_src"], lp["pack_perm"], lp["pack_dst"],
-            lp["seg_offsets"], num_out, interpret=spec.agg_interpret,
+            lp["seg_offsets"], num_out, interpret=pallas_interpret(),
         )
     h_src = mixed[lp["edge_src"]]  # (E, F_in) — the buffer pallas avoids
     return segment_ops.segment_mean(
@@ -133,7 +133,7 @@ def _agg_weighted_sum(
     if spec.agg_backend == "pallas":
         return gather_ops.gather_weighted_segsum(
             mixed_flat, alpha, lp["edge_src"], lp["pack_perm"],
-            lp["pack_dst"], num_out, interpret=spec.agg_interpret,
+            lp["pack_dst"], num_out, interpret=pallas_interpret(),
         )
     E, H = alpha.shape
     dh = mixed_flat.shape[1] // H
@@ -218,7 +218,7 @@ def _half_sum(spec: GNNSpec, rows: jnp.ndarray, lp: dict, side: str,
     if spec.agg_backend == "pallas":
         return gather_ops.gather_segment_sum(
             rows, src, lp[f"{side}pack_perm"], lp[f"{side}pack_dst"],
-            num_out, interpret=spec.agg_interpret,
+            num_out, interpret=pallas_interpret(),
         )
     h_src = rows[src]
     return segment_ops.segment_sum(
@@ -242,7 +242,7 @@ def _half_weighted(spec: GNNSpec, rows: jnp.ndarray, alpha_half: jnp.ndarray,
     if spec.agg_backend == "pallas":
         return gather_ops.gather_weighted_segsum(
             rows, alpha_half, src, lp[f"{side}pack_perm"],
-            lp[f"{side}pack_dst"], num_out, interpret=spec.agg_interpret,
+            lp[f"{side}pack_dst"], num_out, interpret=pallas_interpret(),
         )
     E, Hc = alpha_half.shape
     msg = rows[src].reshape(E, Hc, dh) * alpha_half[:, :, None]
@@ -537,3 +537,39 @@ def gnn_forward_spmd(
             is_last=(li == 0),
         )
     return h
+
+
+def gnn_forward_split_mesh(
+    spec: GNNSpec,
+    mesh,  # launch.sharding.make_split_mesh(R, P): the split axis is minor
+    params: list[dict],
+    h_input: jnp.ndarray,  # (P, N_L, F) — or (P, M, F) misses if cached
+    plan_arrays: dict,  # plan pytree with the leading P axis (sim layout)
+    cache_block: jnp.ndarray | None = None,  # (P, C, F) resident cache
+    rep_block: jnp.ndarray | None = None,  # (R, F_in) replicated input rows
+) -> jnp.ndarray:
+    """``gnn_forward_spmd`` under ``jax.shard_map`` on the mesh's split axis.
+
+    Takes the sim-mode operands of ``gnn_forward``/``gnn_forward_cached``
+    and returns the same (P, N_0, out_dim) logits: the leading P axis of
+    the feature block, the plan and the cache block is sharded over the
+    split axis, so each device runs its own slice and the shuffles are real
+    all-to-alls. ``params`` and ``rep_block`` are replicated. Differentiable
+    with respect to ``params`` and ``h_input``.
+    """
+    axis = mesh.axis_names[-1]
+    split = PartitionSpec(axis)
+
+    def body(params, h, pa, cache, rep):
+        local = lambda t: jax.tree_util.tree_map(lambda x: x[0], t)  # noqa: E731
+        out = gnn_forward_spmd(
+            spec, params, h[0], local(pa), axis,
+            cache_local=None if cache is None else cache[0], rep_block=rep,
+        )
+        return out[None]
+
+    return jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(PartitionSpec(), split, split, split, PartitionSpec()),
+        out_specs=split, check_vma=False,
+    )(params, h_input, plan_arrays, cache_block, rep_block)

@@ -79,7 +79,6 @@ def moe_apply_shard_map(
     experts — GSplit's "send computation to data" applied to tokens — and the
     only collective is one token-dim psum over the model axis per layer.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = jax.sharding.get_abstract_mesh()
@@ -150,7 +149,7 @@ def moe_apply_shard_map(
     xt_all = x.reshape(B * S, d)
     gated = cfg.mlp_type in ("swiglu", "geglu")
     w_gate = params["w_gate"] if gated else params["w_in"]
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -161,7 +160,7 @@ def moe_apply_shard_map(
             P("model", None, None),
         ),
         out_specs=(P(dp, None), P(dp)),
-        check_rep=False,
+        check_vma=False,
     )(xt_all, params["router"], w_gate, params["w_in"], params["w_out"])
     out = y.reshape(B, S, d)
     if cfg.num_shared_experts:

@@ -48,6 +48,7 @@ from repro.graph.sampling import (
     NeighborSampler,
     sample_minibatch,
 )
+from repro.kernels import pallas_interpret
 from repro.kernels.gather_segsum.layout import pow2_at_least
 from repro.sampler.frontier import bucket_by_owner, sorted_unique_capped
 from repro.sampler.ops import wavefront_expand
@@ -185,7 +186,6 @@ def sample_minibatch_spmd(
     axis_name: str,
     num_parts: int,  # static mesh-axis size (sizes the exchange buffers)
     backend: str = "jnp",
-    interpret: bool = True,
 ):
     """The cooperative loop for one shard inside a `shard_map` body.
 
@@ -226,7 +226,8 @@ def sample_minibatch_spmd(
         deg = jnp.where(fvalid, deg, -1)
         codes = wavefront_expand(
             front, deg, layer_keys[l], fanout,
-            backend=backend, interpret=interpret,
+            backend=backend,
+            interpret=backend == "pallas" and pallas_interpret(),
         )
         dst, src, eid, evalid = _decode_edges(
             front, start, codes, dev_local["indices"], dev_local["edge_id"],
@@ -277,7 +278,6 @@ class DeviceSampler:
         seed: int,
         host_sampler: NeighborSampler,
         backend: str = "pallas",
-        interpret: bool = True,
         headroom: float = 1.5,
     ):
         self.graph = graph
@@ -285,7 +285,8 @@ class DeviceSampler:
         self.seed = seed
         self.host = host_sampler
         self.backend = backend
-        self.interpret = interpret
+        # the Pallas kernel interprets on the CPU and compiles on a TPU
+        self.interpret = backend == "pallas" and pallas_interpret()
         self.shards: GraphShards = build_shards(
             graph, np.asarray(assignment), num_devices
         )

@@ -47,7 +47,7 @@ def wavefront_expand_kernel(
     *,
     fanout: int,
     row_block: int = ROW_BLOCK,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Slot codes (B, fanout) int32 — the Pallas realization of the oracle."""
     B = vid.shape[0]

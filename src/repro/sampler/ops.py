@@ -23,7 +23,7 @@ def wavefront_expand(
     fanout: int,
     *,
     backend: str = "pallas",
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Slot codes (B, fanout) int32 (see ``ref`` for the encoding)."""
     key = jnp.asarray(key, jnp.uint32).reshape(1, 2)

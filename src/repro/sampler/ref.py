@@ -44,30 +44,28 @@ def expand_codes(
         vid.astype(jnp.uint32)[:, None], slots.astype(jnp.uint32),
         key_lo, key_hi,
     )
-    degc = jnp.maximum(deg, 1).astype(jnp.uint32)
-    sampled = (u % degc[:, None]).astype(jnp.int32)
+    # The body is written for Mosaic as well as XLA: booleans come only
+    # from comparisons and combine only with &/|/~ (Mosaic cannot select
+    # between, broadcast or reduce bool vectors), and the unsigned modulo is
+    # ``lax.rem`` (``%`` adds a signed-remainder fix-up built from bool
+    # selects; for uint32 both give the same value).
+    degc = jnp.maximum(deg, 1)
+    sampled = jax.lax.rem(u, degc.astype(jnp.uint32)[:, None])
     take_all = (deg <= fanout)[:, None]
-    off = jnp.where(take_all, slots, sampled)
-    valid = jnp.where(
-        (deg < 0)[:, None],
-        False,
-        jnp.where(
-            (deg == 0)[:, None],
-            slots == 0,
-            jnp.where(take_all, slots < deg[:, None], True),
-        ),
+    off = jnp.where(take_all, slots, sampled.astype(jnp.int32))
+    # deg < 0: nothing; deg == 0: slot 0 (the self-loop); deg <= fanout:
+    # the first deg slots; deg > fanout: every slot
+    valid = (deg >= 0)[:, None] & (
+        (slots < degc[:, None]) | (deg > fanout)[:, None]
     )
     off = jnp.where((deg == 0)[:, None] & (slots == 0), SELF_LOOP, off)
     # de-duplicate repeated draws of the same edge: slot j dies if any k < j
     # drew the same offset (take-all offsets are distinct, so only sampled
-    # rows are affected). fanout is small and static — the (B, F, F)
-    # comparison is cheap and avoids data-dependent control flow.
-    eq = off[:, :, None] == off[:, None, :]
-    earlier = (
-        jax.lax.broadcasted_iota(jnp.int32, (fanout, fanout), 1)
-        < jax.lax.broadcasted_iota(jnp.int32, (fanout, fanout), 0)
-    )
-    dup = jnp.any(eq & earlier[None, :, :], axis=-1)
+    # rows are affected). fanout is small and static, so the unrolled
+    # column compares are cheap and avoid data-dependent control flow.
+    dup = slots < 0
+    for k in range(fanout - 1):
+        dup = dup | ((off == off[:, k:k + 1]) & (slots > k))
     valid = valid & ~dup
     return jnp.where(valid, off, INVALID)
 

@@ -88,7 +88,6 @@ class TrainConfig:
     pipeline_depth: int = 4  # max in-flight batches (pipelined source)
     plan_workers: int = 2  # producer threads (pipelined source)
     sampler_backend: str = "pallas"  # device sampling kernel: pallas | jnp
-    sampler_interpret: bool = True  # pallas: interpret mode (CPU); False on TPU
     # Overlap-aware shuffle schedule (DESIGN.md §3a). These are *execution*
     # knobs: the trainer copies them onto the model spec at init, so the
     # jitted step's layer shuffles and the cache remote fetch agree on one
@@ -412,7 +411,6 @@ class Trainer:
                 cfg.seed,
                 host_sampler=self.sampler,
                 backend=cfg.sampler_backend,
-                interpret=cfg.sampler_interpret,
             )
             self.device_sampler.obs = self.obs
         self.recompiles = None
@@ -1278,7 +1276,6 @@ class Trainer:
                 self.cfg.seed,
                 host_sampler=self.sampler,
                 backend=self.cfg.sampler_backend,
-                interpret=self.cfg.sampler_interpret,
             )
             self.device_sampler.obs = self.obs
             self.producer.device_sampler = self.device_sampler

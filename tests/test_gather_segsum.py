@@ -53,10 +53,10 @@ SHAPES = [
 def test_fused_sum_and_mean_match_ref(E, M, F, N):
     rng = np.random.default_rng(E + M)
     mixed, src, dst, mask, pp, pd, so = _random_case(rng, E, M, F, N)
-    out = ops.gather_segment_sum(mixed, src, pp, pd, N)
+    out = ops.gather_segment_sum(mixed, src, pp, pd, N, interpret=True)
     ref = gather_segment_sum_ref(mixed, src, dst, mask, N)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), **TOL)
-    outm = ops.gather_segment_mean(mixed, src, pp, pd, so, N)
+    outm = ops.gather_segment_mean(mixed, src, pp, pd, so, N, interpret=True)
     refm = gather_segment_mean_ref(mixed, src, dst, mask, N)
     np.testing.assert_allclose(np.asarray(outm), np.asarray(refm), **TOL)
 
@@ -66,7 +66,7 @@ def test_fused_sum_grad_matches_ref(E, M, F, N):
     rng = np.random.default_rng(E)
     mixed, src, dst, mask, pp, pd, _ = _random_case(rng, E, M, F, N)
     g1 = jax.grad(
-        lambda m: (ops.gather_segment_sum(m, src, pp, pd, N) ** 2).sum()
+        lambda m: (ops.gather_segment_sum(m, src, pp, pd, N, interpret=True) ** 2).sum()
     )(mixed)
     g2 = jax.grad(
         lambda m: (gather_segment_sum_ref(m, src, dst, mask, N) ** 2).sum()
@@ -79,11 +79,11 @@ def test_fused_weighted_matches_ref_with_grads():
     E, M, H, dh, N = 300, 80, 4, 16, 120
     mixed, src, dst, mask, pp, pd, _ = _random_case(rng, E, M, H * dh, N)
     w = jnp.asarray(rng.normal(size=(E, H)), jnp.float32)
-    out = ops.gather_weighted_segsum(mixed, w, src, pp, pd, N)
+    out = ops.gather_weighted_segsum(mixed, w, src, pp, pd, N, interpret=True)
     ref = gather_weighted_segsum_ref(mixed, w, src, dst, mask, N)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), **TOL)
     gm1, gw1 = jax.grad(
-        lambda m, w: (ops.gather_weighted_segsum(m, w, src, pp, pd, N) ** 2).sum(),
+        lambda m, w: (ops.gather_weighted_segsum(m, w, src, pp, pd, N, interpret=True) ** 2).sum(),
         argnums=(0, 1),
     )(mixed, w)
     gm2, gw2 = jax.grad(
@@ -98,7 +98,7 @@ def test_bf16_storage_f32_accumulation():
     rng = np.random.default_rng(4)
     mixed, src, dst, mask, pp, pd, _ = _random_case(rng, 400, 100, 32, 150)
     m16 = mixed.astype(jnp.bfloat16)
-    out = ops.gather_segment_sum(m16, src, pp, pd, 150)
+    out = ops.gather_segment_sum(m16, src, pp, pd, 150, interpret=True)
     assert out.dtype == jnp.bfloat16
     ref = gather_segment_sum_ref(m16.astype(jnp.float32), src, dst, mask, 150)
     np.testing.assert_allclose(
@@ -182,7 +182,7 @@ def test_repad_preserves_fused_results():
             fused = ops.gather_segment_sum(
                 mixed, jnp.asarray(lp.edge_src[dev]),
                 jnp.asarray(lp.pack_perm[dev]),
-                jnp.asarray(lp.pack_dst[dev]), num_out,
+                jnp.asarray(lp.pack_dst[dev]), num_out, interpret=True,
             )
             ref = gather_segment_sum_ref(
                 mixed, jnp.asarray(lp.edge_src[dev]),
@@ -303,7 +303,8 @@ if HAVE_HYPOTHESIS:
             pp = pad_axis_fill(pad_axis_fill(pp, 1, eb2, E), 0, db2, E)
             pd = pad_axis_fill(pad_axis_fill(pd, 1, eb2, R), 0, db2, R)
         out = ops.gather_segment_sum(
-            mixed, jnp.asarray(src), jnp.asarray(pp), jnp.asarray(pd), num_out
+            mixed, jnp.asarray(src), jnp.asarray(pp), jnp.asarray(pd), num_out,
+            interpret=True,
         )
         ref = gather_segment_sum_ref(
             mixed, jnp.asarray(src), jnp.asarray(dst), jnp.asarray(mask), N

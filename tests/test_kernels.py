@@ -7,7 +7,7 @@ from repro.testing import given, settings, st  # hypothesis or fallback
 
 HAVE_HYPOTHESIS = True  # repro.testing provides a deterministic fallback
 
-from repro.kernels import segment_ops
+from repro.kernels import pallas_interpret, segment_ops
 from repro.kernels.edge_softmax.ops import edge_softmax_pallas
 from repro.kernels.edge_softmax.ref import edge_softmax_ref
 from repro.kernels.segsum.ops import pack_edges, segment_sum_pallas
@@ -23,6 +23,18 @@ SHAPES = [
 ]
 
 
+@pytest.mark.parametrize("platform, interpret", [("cpu", True), ("tpu", False)])
+def test_pallas_interpret_follows_platform(monkeypatch, platform, interpret):
+    monkeypatch.setattr("jax.default_backend", lambda: platform)
+    assert pallas_interpret() is interpret
+
+
+def test_pallas_interpret_refuses_other_platforms(monkeypatch):
+    monkeypatch.setattr("jax.default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        pallas_interpret()
+
+
 @pytest.mark.parametrize("E,F,N", SHAPES)
 @pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16])
 def test_segment_sum_matches_ref(E, F, N, dtype):
@@ -30,7 +42,7 @@ def test_segment_sum_matches_ref(E, F, N, dtype):
     contrib = jnp.asarray(rng.normal(size=(E, F)), dtype)
     dst = rng.integers(0, N, size=E).astype(np.int32)
     mask = rng.random(E) > 0.1
-    out = segment_sum_pallas(contrib, dst, mask, N)
+    out = segment_sum_pallas(contrib, dst, mask, N, interpret=True)
     if dtype == np.float32:
         ref = segment_sum_ref(contrib, jnp.asarray(dst), jnp.asarray(mask), N)
         np.testing.assert_allclose(
@@ -56,7 +68,7 @@ def test_edge_softmax_matches_ref(E, H, N):
     logits = jnp.asarray(rng.normal(size=(E, H)) * 3, jnp.float32)
     dst = rng.integers(0, N, size=E).astype(np.int32)
     mask = rng.random(E) > 0.15
-    out = edge_softmax_pallas(logits, dst, mask, N)
+    out = edge_softmax_pallas(logits, dst, mask, N, interpret=True)
     ref = edge_softmax_ref(logits, jnp.asarray(dst), jnp.asarray(mask), N)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5,
                                atol=2e-6)
@@ -68,7 +80,7 @@ def test_edge_softmax_normalizes():
     logits = jnp.asarray(rng.normal(size=(E, H)), jnp.float32)
     dst = rng.integers(0, N, size=E).astype(np.int32)
     mask = np.ones(E, bool)
-    alpha = np.asarray(edge_softmax_pallas(logits, dst, mask, N))
+    alpha = np.asarray(edge_softmax_pallas(logits, dst, mask, N, interpret=True))
     sums = np.zeros((N, H))
     np.add.at(sums, dst, alpha)
     present = np.bincount(dst, minlength=N) > 0
@@ -155,7 +167,7 @@ if HAVE_HYPOTHESIS:
         contrib = jnp.asarray(rng.normal(size=(E, F)), jnp.float32)
         dst = rng.integers(0, N, size=E).astype(np.int32)
         mask = rng.random(E) > 0.2
-        out = segment_sum_pallas(contrib, dst, mask, N)
+        out = segment_sum_pallas(contrib, dst, mask, N, interpret=True)
         ref = segment_sum_ref(contrib, jnp.asarray(dst), jnp.asarray(mask), N)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=3e-5, atol=3e-5)
@@ -174,7 +186,7 @@ def test_flash_decode_matches_ref(B, H, KV, D, S, L):
     q = jnp.asarray(rng.normal(size=(B, H, D)), jnp.float32)
     k = jnp.asarray(rng.normal(size=(B, S, KV, D)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(B, S, KV, D)), jnp.float32)
-    out = decode_attention_pallas(q, k, v, jnp.int32(L))
+    out = decode_attention_pallas(q, k, v, jnp.int32(L), interpret=True)
     ref = decode_attention_ref(q, k, v, jnp.int32(L))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-5)

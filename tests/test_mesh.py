@@ -177,7 +177,6 @@ def test_replica_psum_equals_hand_average_subprocess():
     _run_sub("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.core.shuffle import replica_grad_mean
         from repro.launch.sharding import make_split_mesh
 
@@ -195,7 +194,7 @@ def test_replica_psum_equals_hand_average_subprocess():
             out = replica_grad_mean(g, "replica", R_DEV)
             return jax.tree_util.tree_map(lambda x: x[None, None], out)
 
-        fn = shard_map(
+        fn = jax.shard_map(
             body, mesh=mesh,
             in_specs=P("replica", "split"), out_specs=P("replica", "split"),
         )
@@ -220,7 +219,6 @@ def test_spmd_2x2_mesh_matches_sim_subprocess():
     _run_sub("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.core import (
             presample, partition_graph, build_split_plan, sim_shuffle,
         )
@@ -274,12 +272,12 @@ def test_spmd_2x2_mesh_matches_sim_subprocess():
             )
             return out[None, None]
 
-        fn = shard_map(
+        fn = jax.jit(jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(), P("replica", "split"), pa_specs),
             out_specs=P("replica", "split"),
-            check_rep=False,
-        )
+            check_vma=False,
+        ))
         got = fn(params, feats, pa)
 
         refs = [

@@ -93,7 +93,7 @@ def test_replicated_matches_nonreplicated_forward(
     ds, part, mb = setup
     spec = GNNSpec(
         model=model, in_dim=ds.spec.feat_dim, hidden_dim=16, out_dim=4,
-        num_layers=2, num_heads=2, agg_backend=backend, agg_interpret=True,
+        num_layers=2, num_heads=2, agg_backend=backend,
         overlap=overlap, shuffle_chunks=2 if overlap else 1,
     )
     out0, out1 = _forwards(ds, part, mb, spec)
@@ -174,15 +174,14 @@ def test_sim_matches_spmd_with_replication():
     code = """
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.graph.datasets import make_dataset
         from repro.graph.sampling import sample_minibatch
         from repro.core import (
             presample, partition_graph, build_split_plan, sim_shuffle,
         )
-        from repro.launch.sharding import replicated_block_specs
+        from repro.launch.sharding import make_split_mesh, replicated_block_specs
         from repro.models.gnn import GNNSpec, init_gnn_params
-        from repro.models.gnn.layers import gnn_forward, gnn_forward_spmd
+        from repro.models.gnn.layers import gnn_forward, gnn_forward_split_mesh
         from repro.train.plan_io import plan_to_device, load_features
 
         NDEV = 4
@@ -198,7 +197,7 @@ def test_sim_matches_spmd_with_replication():
             ds.features[rep.vertices].astype(np.float32))
         (rep_spec,) = replicated_block_specs((rep_block,))
         assert rep_spec == P(None, None)
-        mesh = jax.make_mesh((NDEV,), ("model",))
+        mesh = make_split_mesh(1, NDEV)
 
         for overlap in (False, True):
             plan = build_split_plan(mb, part.assignment, NDEV,
@@ -212,17 +211,8 @@ def test_sim_matches_spmd_with_replication():
             params = init_gnn_params(jax.random.PRNGKey(0), spec)
             ref = gnn_forward(spec, params, feats, pa, sim_shuffle,
                               rep_block=rep_block)
-            def body(feats_l, pa_l, rb):
-                pa_dev = jax.tree_util.tree_map(lambda x: x[0], pa_l)
-                out = gnn_forward_spmd(spec, params, feats_l[0], pa_dev,
-                                       "model", rep_block=rb)
-                return out[None]
-            fn = shard_map(
-                body, mesh=mesh,
-                in_specs=(P("model"), P("model"), rep_spec),
-                out_specs=P("model"), check_rep=False,
-            )
-            got = fn(feats, pa, rep_block)
+            got = jax.jit(lambda f, rb: gnn_forward_split_mesh(
+                spec, mesh, params, f, pa, rep_block=rb))(feats, rep_block)
             np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                        rtol=2e-5, atol=2e-5)
             print("overlap", overlap, "OK")

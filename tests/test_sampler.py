@@ -101,7 +101,7 @@ def test_expand_semantics_match_host_sampler(ds):
     codes = np.asarray(
         wavefront_expand(
             vids, degj, jnp.asarray([123, 456], jnp.uint32), fanout,
-            backend="jnp",
+            backend="jnp", interpret=True,
         )
     )
     for i in range(len(deg)):
@@ -390,13 +390,12 @@ def test_spmd_sampling_matches_sim_and_trains():
     code = textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.graph.datasets import make_dataset
         from repro.graph.sampling import NeighborSampler
         from repro.core import presample, partition_graph, build_split_plan, sim_shuffle
-        from repro.launch.sharding import sampler_shard_specs
+        from repro.launch.sharding import make_split_mesh, sampler_shard_specs
         from repro.models.gnn import GNNSpec, init_gnn_params
-        from repro.models.gnn.layers import gnn_forward, gnn_forward_spmd
+        from repro.models.gnn.layers import gnn_forward, gnn_forward_split_mesh
         from repro.sampler import DeviceSampler, sample_minibatch_spmd
         from repro.sampler.engine import _sample_device
         from repro.train.plan_io import plan_to_device, load_features
@@ -417,25 +416,25 @@ def test_spmd_sampling_matches_sim_and_trains():
                              jnp.int32(len(targets)), keys, caps=caps,
                              fanouts=FANOUTS, backend="jnp", interpret=True)
 
-        mesh = jax.make_mesh((NDEV,), ("model",))
-        specs = sampler_shard_specs(eng._dev)
+        mesh = make_split_mesh(1, NDEV)
+        specs = sampler_shard_specs(eng._dev, split_axis="split")
         def body(dev):
-            dev_local = {k: (v[0] if specs[k][0] == "model" else v)
+            dev_local = {k: (v[0] if specs[k][0] == "split" else v)
                          for k, v in dev.items()}
             fronts, counts, layers, flags = sample_minibatch_spmd(
                 dev_local, jnp.asarray(tpad), jnp.int32(len(targets)), keys,
-                caps=caps, fanouts=FANOUTS, axis_name="model",
+                caps=caps, fanouts=FANOUTS, axis_name="split",
                 num_parts=NDEV, backend="jnp")
             return ([f[None] for f in fronts], [c[None] for c in counts],
                     [{k: v[None] for k, v in l.items()} for l in layers],
                     {k: v[None] for k, v in flags.items()})
         flag_keys = ("N0", "N1", "N2", "C0", "C1", "X0", "X1")
-        out_specs = ([P("model")] * 3, [P("model")] * 3,
-                     [{k: P("model") for k in ("dst", "src", "eid", "valid")}
+        out_specs = ([P("split")] * 3, [P("split")] * 3,
+                     [{k: P("split") for k in ("dst", "src", "eid", "valid")}
                       for _ in FANOUTS],
-                     {k: P("model") for k in flag_keys})
-        fn = shard_map(body, mesh=mesh, in_specs=(specs,),
-                       out_specs=out_specs, check_rep=False)
+                     {k: P("split") for k in flag_keys})
+        fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(specs,),
+                                   out_specs=out_specs, check_vma=False))
         got = fn(eng._dev)
         for d in range(3):
             np.testing.assert_array_equal(np.asarray(got[0][d]),
@@ -462,15 +461,8 @@ def test_spmd_sampling_matches_sim_and_trains():
                            out_dim=4, num_layers=2, num_heads=2)
             params = init_gnn_params(jax.random.PRNGKey(0), spec)
             ref_out = gnn_forward(spec, params, feats, pa, sim_shuffle)
-            def fwd(prms, feats_in):
-                def fwd_body(feats_l, pa_l):
-                    pa_dev = jax.tree_util.tree_map(lambda x: x[0], pa_l)
-                    return gnn_forward_spmd(spec, prms, feats_l[0], pa_dev,
-                                            "model")[None]
-                return shard_map(fwd_body, mesh=mesh,
-                                 in_specs=(P("model"), P("model")),
-                                 out_specs=P("model"), check_rep=False)(
-                    feats_in, pa)
+            fwd = jax.jit(lambda prms, feats_in: gnn_forward_split_mesh(
+                spec, mesh, prms, feats_in, pa))
             out = fwd(params, feats)
             np.testing.assert_allclose(np.asarray(out), np.asarray(ref_out),
                                        rtol=2e-5, atol=2e-5)

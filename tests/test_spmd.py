@@ -25,16 +25,37 @@ def _run(code: str, devices: int = 8, timeout: int = 560) -> str:
     return out.stdout
 
 
+# one sampled split plan on the tiny graph, shared by the forward tests
+_TINY_PLAN = """
+        import jax, jax.numpy as jnp, numpy as np
+        from repro.graph.datasets import make_dataset
+        from repro.graph.sampling import sample_minibatch
+        from repro.core import presample, partition_graph, build_split_plan, sim_shuffle
+        from repro.launch.sharding import make_split_mesh
+        from repro.models.gnn import GNNSpec, init_gnn_params
+        from repro.models.gnn.layers import gnn_forward, gnn_forward_split_mesh
+        from repro.train.plan_io import plan_to_device, load_features
+
+        NDEV = 4
+        ds = make_dataset("tiny")
+        rng = np.random.default_rng(0)
+        mb = sample_minibatch(ds.graph, ds.train_ids[:16], [3, 3], rng)
+        w = presample(ds.graph, ds.train_ids, [3, 3], 16, num_epochs=1)
+        part = partition_graph(ds.graph, NDEV, method="gsplit", weights=w)
+        mesh = make_split_mesh(1, NDEV)
+"""
+
+
 def test_spmd_shuffle_matches_sim():
     """shard_map all-to-all shuffle == simulated shuffle, fwd and grad."""
     _run("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax.sharding import PartitionSpec as P
         from repro.core.shuffle import sim_shuffle, spmd_shuffle
+        from repro.launch.sharding import make_split_mesh
 
         P_DEV, N, S, F = 4, 8, 3, 5
-        mesh = jax.make_mesh((P_DEV,), ("model",))
+        mesh = make_split_mesh(1, P_DEV)
         rng = np.random.default_rng(0)
         h = jnp.asarray(rng.normal(size=(P_DEV, N, F)), jnp.float32)
         send_idx = jnp.asarray(
@@ -42,11 +63,11 @@ def test_spmd_shuffle_matches_sim():
 
         ref = sim_shuffle(h, send_idx)
 
-        fn = shard_map(
-            lambda hl, si: spmd_shuffle(hl[0], si[0], "model")[None],
+        fn = jax.shard_map(
+            lambda hl, si: spmd_shuffle(hl[0], si[0], "split")[None],
             mesh=mesh,
-            in_specs=(P("model"), P("model")),
-            out_specs=P("model"),
+            in_specs=(P("split"), P("split")),
+            out_specs=P("split"),
         )
         got = fn(h, send_idx)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=1e-6)
@@ -65,23 +86,7 @@ def test_spmd_shuffle_matches_sim():
 
 def test_spmd_gnn_forward_matches_sim():
     """Full split-parallel GNN forward under shard_map == sim mode."""
-    _run("""
-        import jax, jax.numpy as jnp, numpy as np
-        from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
-        from repro.graph.datasets import make_dataset
-        from repro.graph.sampling import sample_minibatch
-        from repro.core import presample, partition_graph, build_split_plan, sim_shuffle
-        from repro.models.gnn import GNNSpec, init_gnn_params
-        from repro.models.gnn.layers import gnn_forward, gnn_forward_spmd
-        from repro.train.plan_io import plan_to_device, load_features
-
-        NDEV = 4
-        ds = make_dataset("tiny")
-        rng = np.random.default_rng(0)
-        mb = sample_minibatch(ds.graph, ds.train_ids[:16], [3, 3], rng)
-        w = presample(ds.graph, ds.train_ids, [3, 3], 16, num_epochs=1)
-        part = partition_graph(ds.graph, NDEV, method="gsplit", weights=w)
+    _run(_TINY_PLAN + """
         plan = build_split_plan(mb, part.assignment, NDEV)
         pa = plan_to_device(plan)
         feats = jnp.asarray(load_features(plan, ds.features))
@@ -91,19 +96,7 @@ def test_spmd_gnn_forward_matches_sim():
         params = init_gnn_params(jax.random.PRNGKey(0), spec)
 
         ref = gnn_forward(spec, params, feats, pa, sim_shuffle)
-
-        mesh = jax.make_mesh((NDEV,), ("model",))
-        def body(feats_l, pa_l):
-            pa_dev = jax.tree_util.tree_map(lambda x: x[0], pa_l)
-            out = gnn_forward_spmd(spec, params, feats_l[0], pa_dev, "model")
-            return out[None]
-        fn = shard_map(
-            body, mesh=mesh,
-            in_specs=(P("model"), P("model")),
-            out_specs=P("model"),
-            check_rep=False,
-        )
-        got = fn(feats, pa)
+        got = gnn_forward_split_mesh(spec, mesh, params, feats, pa)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
         print("OK")
@@ -114,46 +107,22 @@ def test_spmd_gnn_forward_pallas_backend_matches_jnp():
     """The fused Pallas aggregation under shard_map == the jnp backend in sim
     mode — the layer-centric kernel is the same black box on both paths
     (docs/KERNELS.md)."""
-    _run("""
-        import jax, jax.numpy as jnp, numpy as np
+    _run(_TINY_PLAN + """
         from dataclasses import replace
-        from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
-        from repro.graph.datasets import make_dataset
-        from repro.graph.sampling import sample_minibatch
-        from repro.core import presample, partition_graph, build_split_plan, sim_shuffle
-        from repro.models.gnn import GNNSpec, init_gnn_params
-        from repro.models.gnn.layers import gnn_forward, gnn_forward_spmd
-        from repro.train.plan_io import plan_to_device, load_features
 
-        NDEV = 4
-        ds = make_dataset("tiny")
-        rng = np.random.default_rng(0)
-        mb = sample_minibatch(ds.graph, ds.train_ids[:16], [3, 3], rng)
-        w = presample(ds.graph, ds.train_ids, [3, 3], 16, num_epochs=1)
-        part = partition_graph(ds.graph, NDEV, method="gsplit", weights=w)
         plan = build_split_plan(mb, part.assignment, NDEV)
         pa = plan_to_device(plan)
         feats = jnp.asarray(load_features(plan, ds.features))
 
-        mesh = jax.make_mesh((NDEV,), ("model",))
         for model in ("sage", "gcn", "gat"):
             spec = GNNSpec(model=model, in_dim=ds.spec.feat_dim, hidden_dim=16,
                            out_dim=4, num_layers=2, num_heads=2)
             spec_p = replace(spec, agg_backend="pallas")
             params = init_gnn_params(jax.random.PRNGKey(0), spec)
             ref = gnn_forward(spec, params, feats, pa, sim_shuffle)
-            def body(feats_l, pa_l):
-                pa_dev = jax.tree_util.tree_map(lambda x: x[0], pa_l)
-                out = gnn_forward_spmd(spec_p, params, feats_l[0], pa_dev, "model")
-                return out[None]
-            fn = shard_map(
-                body, mesh=mesh,
-                in_specs=(P("model"), P("model")),
-                out_specs=P("model"),
-                check_rep=False,
-            )
-            got = fn(feats, pa)
+            got = jax.jit(
+                lambda f: gnn_forward_split_mesh(spec_p, mesh, params, f, pa)
+            )(feats)
             np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                        rtol=5e-5, atol=5e-5)
             print(model, "OK")
@@ -165,27 +134,10 @@ def test_spmd_overlap_matches_sim():
     (forward and gradients), for all three models, chunked, on both wire
     dtypes — the SpmdComm adapter must mirror SimComm exactly
     (DESIGN.md §3a)."""
-    _run("""
-        import jax, jax.numpy as jnp, numpy as np
-        from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
-        from repro.graph.datasets import make_dataset
-        from repro.graph.sampling import sample_minibatch
-        from repro.core import presample, partition_graph, build_split_plan, sim_shuffle
-        from repro.models.gnn import GNNSpec, init_gnn_params
-        from repro.models.gnn.layers import gnn_forward, gnn_forward_spmd
-        from repro.train.plan_io import plan_to_device, load_features
-
-        NDEV = 4
-        ds = make_dataset("tiny")
-        rng = np.random.default_rng(0)
-        mb = sample_minibatch(ds.graph, ds.train_ids[:16], [3, 3], rng)
-        w = presample(ds.graph, ds.train_ids, [3, 3], 16, num_epochs=1)
-        part = partition_graph(ds.graph, NDEV, method="gsplit", weights=w)
+    _run(_TINY_PLAN + """
         plan = build_split_plan(mb, part.assignment, NDEV, with_halves=True)
         pa = plan_to_device(plan, with_halves=True)
         feats = jnp.asarray(load_features(plan, ds.features))
-        mesh = jax.make_mesh((NDEV,), ("model",))
 
         for model in ("sage", "gcn", "gat"):
             for wire in ("float32", "bfloat16"):
@@ -195,22 +147,15 @@ def test_spmd_overlap_matches_sim():
                                wire_dtype=wire)
                 params = init_gnn_params(jax.random.PRNGKey(0), spec)
                 ref = gnn_forward(spec, params, feats, pa, sim_shuffle)
-                def body(feats_l, pa_l):
-                    pa_dev = jax.tree_util.tree_map(lambda x: x[0], pa_l)
-                    out = gnn_forward_spmd(spec, params, feats_l[0], pa_dev,
-                                           "model")
-                    return out[None]
-                fn = shard_map(
-                    body, mesh=mesh,
-                    in_specs=(P("model"), P("model")),
-                    out_specs=P("model"), check_rep=False,
+                fn = jax.jit(
+                    lambda h: gnn_forward_split_mesh(spec, mesh, params, h, pa)
                 )
-                got = fn(feats, pa)
+                got = fn(feats)
                 np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                            rtol=2e-5, atol=2e-5)
                 g1 = jax.grad(lambda h: (gnn_forward(
                     spec, params, h, pa, sim_shuffle) ** 2).sum())(feats)
-                g2 = jax.grad(lambda h: (fn(h, pa) ** 2).sum())(feats)
+                g2 = jax.grad(lambda h: (fn(h) ** 2).sum())(feats)
                 np.testing.assert_allclose(np.asarray(g2), np.asarray(g1),
                                            rtol=2e-4, atol=2e-4)
                 print(model, wire, "OK")
@@ -221,29 +166,13 @@ def test_spmd_cache_serving_matches_sim():
     """shard_map cache serving (sharded resident block + all-to-all remote
     fetch) == sim serving == full host gather, and the cached spmd forward
     matches the sim forward fed by ``load_features``."""
-    _run("""
-        import jax, jax.numpy as jnp, numpy as np
+    _run(_TINY_PLAN + """
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
-        from repro.graph.datasets import make_dataset
-        from repro.graph.sampling import sample_minibatch
-        from repro.core import presample, partition_graph, build_split_plan, sim_shuffle
         from repro.core.shuffle import sim_serve_features, spmd_serve_features
         from repro.graph.cache import FeatureCache
         from repro.launch.sharding import split_cache_specs
-        from repro.models.gnn import GNNSpec, init_gnn_params
-        from repro.models.gnn.layers import gnn_forward, gnn_forward_spmd
-        from repro.train.plan_io import (
-            cache_plan_to_device, load_features, load_miss_features,
-            plan_to_device,
-        )
+        from repro.train.plan_io import cache_plan_to_device, load_miss_features
 
-        NDEV = 4
-        ds = make_dataset("tiny")
-        rng = np.random.default_rng(0)
-        mb = sample_minibatch(ds.graph, ds.train_ids[:16], [3, 3], rng)
-        w = presample(ds.graph, ds.train_ids, [3, 3], 16, num_epochs=1)
-        part = partition_graph(ds.graph, NDEV, method="gsplit", weights=w)
         plan = build_split_plan(mb, part.assignment, NDEV)
         cache = FeatureCache(ds.graph.num_nodes, NDEV, 24,
                              ranking=w.vertex_weight, mode="distributed",
@@ -258,13 +187,12 @@ def test_spmd_cache_serving_matches_sim():
         ref = sim_serve_features(block, cpd, miss)
         np.testing.assert_array_equal(np.asarray(ref), want)
 
-        mesh = jax.make_mesh((NDEV,), ("model",))
-        specs = split_cache_specs((block, cpd, miss))
-        fn = shard_map(
+        specs = split_cache_specs((block, cpd, miss), split_axis="split")
+        fn = jax.shard_map(
             lambda b, c, m: spmd_serve_features(
-                b[0], jax.tree_util.tree_map(lambda x: x[0], c), m[0], "model"
+                b[0], jax.tree_util.tree_map(lambda x: x[0], c), m[0], "split"
             )[None],
-            mesh=mesh, in_specs=specs, out_specs=P("model"),
+            mesh=mesh, in_specs=specs, out_specs=P("split"),
         )
         got = fn(block, cpd, miss)
         np.testing.assert_array_equal(np.asarray(got), want)
@@ -275,17 +203,8 @@ def test_spmd_cache_serving_matches_sim():
         params = init_gnn_params(jax.random.PRNGKey(0), spec)
         pa = plan_to_device(plan, cp)
         ref_out = gnn_forward(spec, params, jnp.asarray(want), pa, sim_shuffle)
-        def body(b, m, pa_l):
-            pa_dev = jax.tree_util.tree_map(lambda x: x[0], pa_l)
-            out = gnn_forward_spmd(spec, params, m[0], pa_dev, "model",
-                                   cache_local=b[0])
-            return out[None]
-        fwd = shard_map(
-            body, mesh=mesh,
-            in_specs=(P("model"), P("model"), P("model")),
-            out_specs=P("model"), check_rep=False,
-        )
-        out = fwd(block, miss, pa)
+        out = gnn_forward_split_mesh(spec, mesh, params, miss, pa,
+                                     cache_block=block)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref_out),
                                    rtol=2e-5, atol=2e-5)
         print("OK")
@@ -293,11 +212,7 @@ def test_spmd_cache_serving_matches_sim():
 
 
 @pytest.mark.slow
-@pytest.mark.skipif(
-    not hasattr(__import__("jax"), "set_mesh"),
-    reason="repro.launch.dryrun uses jax.set_mesh (not in the pinned jax)",
-)
-def test_dryrun_one_combo_subprocess():
+def test_dryrun_one_combo_subprocess(tmp_path):
     """The dry-run driver lowers+compiles a full production combo (512 dev)."""
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC
@@ -305,7 +220,7 @@ def test_dryrun_one_combo_subprocess():
         [
             sys.executable, "-m", "repro.launch.dryrun",
             "--arch", "smollm-135m", "--shape", "decode_32k",
-            "--out", "/tmp/dryrun_test",
+            "--out", str(tmp_path),
         ],
         capture_output=True, text=True, env=env, timeout=560,
     )

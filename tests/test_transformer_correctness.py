@@ -260,10 +260,6 @@ def test_flash_unrolled_matches_scan():
                                rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.skipif(
-    not hasattr(jax, "set_mesh"),
-    reason="subprocess uses jax.set_mesh (not in the pinned jax)",
-)
 def test_moe_shard_map_matches_pjit_subprocess():
     """§Perf A4: expert-local shard_map dispatch == global pjit dispatch."""
     import os
@@ -278,7 +274,9 @@ def test_moe_shard_map_matches_pjit_subprocess():
         import dataclasses, jax, jax.numpy as jnp, numpy as np
         from repro.models.transformer.config import ArchConfig
         from repro.models.transformer.moe import moe_apply, moe_init
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AxisType.Auto, AxisType.Auto))
         jax.set_mesh(mesh)
         cfg = ArchConfig(name="t", family="moe", num_layers=1, d_model=16,
                          vocab_size=32, num_experts=8, moe_top_k=2, moe_d_ff=8,
