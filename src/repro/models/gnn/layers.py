@@ -10,6 +10,7 @@ Supported models: GraphSAGE (mean), GAT (multi-head attention), GCN.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 
 import jax
@@ -106,6 +107,21 @@ def init_gnn_params(key: jax.Array, spec: GNNSpec) -> list[dict]:
     return params
 
 
+def _scoped(name: str):
+    """Run the decorated function under ``jax.named_scope(name)`` (a fresh
+    scope per call: one shared scope object is not safe across threads)."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return scoped
+    return wrap
+
+
+# Every aggregation runs under the ``agg`` scope, whichever backend
+# implements it, so one name in the trace reads aggregation time.
+@_scoped("agg")
 def _agg_mean(spec: GNNSpec, mixed: jnp.ndarray, lp: dict, num_out: int):
     """Masked mean of ``mixed[edge_src]`` per destination, backend-dispatched.
 
@@ -125,6 +141,7 @@ def _agg_mean(spec: GNNSpec, mixed: jnp.ndarray, lp: dict, num_out: int):
     )
 
 
+@_scoped("agg")
 def _agg_weighted_sum(
     spec: GNNSpec, mixed_flat: jnp.ndarray, alpha: jnp.ndarray, lp: dict,
     num_out: int,
@@ -189,9 +206,10 @@ def gnn_layer_apply(
         # backends: it is H/dh-times smaller than the feature traffic, and
         # keeping one implementation makes the backends agree on alpha
         # bit-for-bit (only the weighted sum below differs, by fp tolerance)
-        alpha = segment_ops.edge_softmax(
-            logits, edge_dst, edge_mask, num_out
-        )  # (E, H)
+        with jax.named_scope("softmax"):
+            alpha = segment_ops.edge_softmax(
+                logits, edge_dst, edge_mask, num_out
+            )  # (E, H)
         agg = _agg_weighted_sum(
             spec, wh.reshape(wh.shape[0], H * dh), alpha, lp, num_out
         )
@@ -203,6 +221,7 @@ def gnn_layer_apply(
     return out
 
 
+@_scoped("agg")
 def _half_sum(spec: GNNSpec, rows: jnp.ndarray, lp: dict, side: str,
               num_out: int) -> jnp.ndarray:
     """Per-device partial sum over one edge half (``side`` in {"l", "r"}).
@@ -226,6 +245,7 @@ def _half_sum(spec: GNNSpec, rows: jnp.ndarray, lp: dict, side: str,
     )
 
 
+@_scoped("agg")
 def _half_weighted(spec: GNNSpec, rows: jnp.ndarray, alpha_half: jnp.ndarray,
                    lp: dict, side: str, num_out: int, dh: int) -> jnp.ndarray:
     """Per-device weighted partial sum over one edge half (GAT).
@@ -378,9 +398,10 @@ def _gnn_layer_overlap(
                 ssrc[l["edge_src"]] + s_dst_n[l["edge_dst"]],
                 negative_slope=0.2,
             )
-            return segment_ops.edge_softmax(
-                logits, l["edge_dst"], l["edge_mask"], num_out
-            )
+            with jax.named_scope("softmax"):
+                return segment_ops.edge_softmax(
+                    logits, l["edge_dst"], l["edge_mask"], num_out
+                )
 
         alpha = B(_alpha)(s_src_mix, wh, lp_v)  # (..., E, H)
 
@@ -443,20 +464,22 @@ def gnn_forward(
         num_out = lp["self_pos"].shape[-1]  # static: N_i
         layer_params = params[L - 1 - li]  # params[0] consumes input features
         rep = rep_block if li == L - 1 else None
-        if spec.overlap:
-            h = _gnn_layer_overlap(
-                spec, layer_params, h, lp, num_out, li == 0, SimComm(),
-                rep_block=rep,
+        with jax.named_scope(f"gnn/layer{L - 1 - li}"):
+            if spec.overlap:
+                h = _gnn_layer_overlap(
+                    spec, layer_params, h, lp, num_out, li == 0, SimComm(),
+                    rep_block=rep,
+                )
+                continue
+            with jax.named_scope("shuffle"):
+                mixed = shuffle_fn(h, lp["send_idx"], spec.wire_dtype)  # (P, M, F)
+            if rep is not None:
+                mixed = sim_append_replicated(mixed, rep)
+            lp_dev = {k: v for k, v in lp.items() if k != "send_idx"}
+            apply_one = lambda m, l: gnn_layer_apply(  # noqa: E731
+                spec, layer_params, m, l, num_out, is_last=(li == 0)
             )
-            continue
-        mixed = shuffle_fn(h, lp["send_idx"], spec.wire_dtype)  # (P, M, F)
-        if rep is not None:
-            mixed = sim_append_replicated(mixed, rep)
-        lp_dev = {k: v for k, v in lp.items() if k != "send_idx"}
-        apply_one = lambda m, l: gnn_layer_apply(  # noqa: E731
-            spec, layer_params, m, l, num_out, is_last=(li == 0)
-        )
-        h = jax.vmap(apply_one)(mixed, lp_dev)
+            h = jax.vmap(apply_one)(mixed, lp_dev)
     return h
 
 
@@ -519,23 +542,27 @@ def gnn_forward_spmd(
         lp = plan_arrays["layers"][li]
         num_out = lp["self_pos"].shape[-1]
         rep = rep_block if li == L - 1 else None
-        if spec.overlap:
-            h = _gnn_layer_overlap(
-                spec, params[L - 1 - li], h, lp, num_out, li == 0,
-                SpmdComm(axis_name), rep_block=rep,
+        with jax.named_scope(f"gnn/layer{L - 1 - li}"):
+            if spec.overlap:
+                h = _gnn_layer_overlap(
+                    spec, params[L - 1 - li], h, lp, num_out, li == 0,
+                    SpmdComm(axis_name), rep_block=rep,
+                )
+                continue
+            with jax.named_scope("shuffle"):
+                mixed = spmd_shuffle(
+                    h, lp["send_idx"], axis_name, spec.wire_dtype
+                )
+            if rep is not None:
+                mixed = spmd_append_replicated(mixed, rep)
+            h = gnn_layer_apply(
+                spec,
+                params[L - 1 - li],
+                mixed,
+                lp,
+                num_out,
+                is_last=(li == 0),
             )
-            continue
-        mixed = spmd_shuffle(h, lp["send_idx"], axis_name, spec.wire_dtype)
-        if rep is not None:
-            mixed = spmd_append_replicated(mixed, rep)
-        h = gnn_layer_apply(
-            spec,
-            params[L - 1 - li],
-            mixed,
-            lp,
-            num_out,
-            is_last=(li == 0),
-        )
     return h
 
 

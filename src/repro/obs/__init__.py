@@ -8,7 +8,9 @@ asks (DESIGN.md §10, docs/OBSERVABILITY.md):
     Disabled (``NULL_OBS``, the default everywhere) it records nothing and
     adds no host syncs: spans still time their region (the trainer's
     ``EpochStats`` fields read those durations — one code path), metric
-    calls return after a single attribute check.
+    calls return after a single attribute check. Enabled, each span is
+    also a ``jax.profiler.TraceAnnotation``, so a profiler trace shows
+    the program's spans beside the device ops.
   * ``python -m repro.obs report trace.json`` summarizes a written trace:
     per-stage percentiles plus a producer-bound / staging-bound /
     device-bound stall classification per step.
@@ -49,9 +51,14 @@ class Obs:
         )
 
     # ---- spans -------------------------------------------------------- #
-    def span(self, name: str, attrs=None) -> Span:
-        """A timed region; recorded only when enabled, timed always."""
-        return Span(self.tracer, name, attrs)
+    def span(self, name: str, attrs=None, *, cpu: bool = False,
+             step_num: int | None = None) -> Span:
+        """A timed region; recorded only when enabled, timed always.
+
+        When enabled, ``cpu`` adds the thread's CPU seconds as ``cpu_s`` and
+        ``step_num`` marks the span as a training step on the profiler's
+        clock (see ``repro.obs.trace``)."""
+        return Span(self.tracer, name, attrs, cpu=cpu, step_num=step_num)
 
     def record(self, name: str, t0: float, t1: float, attrs=None) -> None:
         if self.tracer is not None:

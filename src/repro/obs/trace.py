@@ -30,6 +30,16 @@ Flow events link a producer thread's ``plan/build`` span to the consumer
 ``(epoch, batch)`` id: the producer records the *start* point inside its
 build span, the consumer records the *finish* point inside its step span,
 and the exporter emits a Chrome ``s``/``f`` pair per resolved id.
+
+On the profiler's clock: with a tracer attached, every span also enters a
+``jax.profiler.TraceAnnotation`` of its name (a ``StepTraceAnnotation``
+named ``train`` for a span given ``step_num``), and its scalar attrs become
+the annotation's metadata at exit. Under ``jax.profiler.trace`` the spans
+thus land in the profiler's host plane, on the thread that ran them and on
+the device ops' clock; outside a profiler session an annotation is a no-op.
+A span made with ``cpu=True`` also records ``cpu_s``, the thread's CPU
+seconds over the span (``time.thread_time``), so a producer that waits on
+the GIL or a lock reads less CPU than wall time.
 """
 from __future__ import annotations
 
@@ -39,7 +49,12 @@ import time
 from collections import deque
 from dataclasses import dataclass
 
+import jax
+
 __all__ = ["Span", "SpanEvent", "Tracer"]
+
+#: the profiler name of a span given ``step_num`` (xprof's step view)
+STEP_TRACE_NAME = "train"
 
 
 @dataclass(frozen=True)
@@ -62,20 +77,25 @@ class Span:
     ``duration`` is valid after ``__exit__`` whether or not a tracer is
     attached — the trainer's stage timings (``EpochStats.t_sample`` etc.)
     read it on the disabled path too, so tracing on/off shares one timing
-    code path.
+    code path. Without a tracer a span makes the two ``perf_counter`` calls
+    and nothing else: no annotation, no ``thread_time``.
     """
 
-    __slots__ = ("_tracer", "name", "attrs", "t0", "t1")
+    __slots__ = ("_tracer", "name", "attrs", "t0", "t1", "cpu", "step_num",
+                 "_annotation", "_c0")
 
-    def __init__(self, tracer: "Tracer | None", name: str, attrs=None):
+    def __init__(self, tracer: "Tracer | None", name: str, attrs=None, *,
+                 cpu: bool = False, step_num: int | None = None):
         self._tracer = tracer
         self.name = name
         self.attrs = attrs
+        self.cpu = cpu
+        self.step_num = step_num
         self.t0 = self.t1 = 0.0
 
     def __enter__(self) -> "Span":
         if self._tracer is not None:
-            self._tracer._enter()
+            self._tracer._enter(self)
         self.t0 = time.perf_counter()
         return self
 
@@ -84,9 +104,21 @@ class Span:
         if self._tracer is not None:
             self._tracer._exit(self)
 
+    def set(self, **attrs) -> None:
+        """Add attrs (counters known only inside the span) before exit."""
+        if self.attrs is None:
+            self.attrs = attrs
+        else:
+            self.attrs.update(attrs)
+
     @property
     def duration(self) -> float:
         return self.t1 - self.t0
+
+
+def _scalars(attrs: dict) -> dict:
+    """The attrs a profiler annotation can carry as metadata."""
+    return {k: v for k, v in attrs.items() if isinstance(v, (int, float, str))}
 
 
 class _ThreadRing:
@@ -139,13 +171,29 @@ class Tracer:
                 self._rings.append(ring)
         return ring
 
-    def span(self, name: str, attrs=None) -> Span:
-        return Span(self, name, attrs)
+    def span(self, name: str, attrs=None, **kw) -> Span:
+        return Span(self, name, attrs, **kw)
 
-    def _enter(self) -> None:
+    def _enter(self, span: Span) -> None:
         self._ring().open_depth += 1
+        if span.step_num is None:
+            ann = jax.profiler.TraceAnnotation(span.name)
+        else:
+            ann = jax.profiler.StepTraceAnnotation(
+                STEP_TRACE_NAME, step_num=span.step_num
+            )
+        ann.__enter__()
+        span._annotation = ann
+        if span.cpu:
+            span._c0 = time.thread_time()
 
     def _exit(self, span: Span) -> None:
+        if span.cpu:
+            span.set(cpu_s=time.thread_time() - span._c0)
+        ann = span._annotation
+        if span.attrs:
+            ann.set_metadata(**_scalars(span.attrs))
+        ann.__exit__(None, None, None)
         ring = self._ring()
         ring.open_depth -= 1
         ring.append(

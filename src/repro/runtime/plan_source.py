@@ -47,7 +47,7 @@ from repro.faults.retry import RetryPolicy
 from repro.graph.cache import CachePlan, FeatureCache, LoadBreakdown
 from repro.graph.sampling import NeighborSampler
 from repro.obs import NULL_OBS, Obs, note_hwm_growth
-from repro.runtime.prefetch import OrderedPrefetcher
+from repro.runtime.prefetch import OrderedPrefetcher, current_worker
 from repro.runtime.signature import SignatureCache, mesh_signature, plan_signature
 
 # NOTE: repro.train.plan_io is imported lazily inside PlanProducer.build —
@@ -104,6 +104,15 @@ class MeshPlanBatch:
     @property
     def num_replicas(self) -> int:
         return len(self.parts)
+
+
+def _load_counters(span, plan, cache_plan, features: np.ndarray) -> None:
+    """``plan/load``'s ``rows`` (the true rows gathered) and ``bytes`` (what
+    they read from the feature table)."""
+    from repro.train.plan_io import true_feature_rows
+
+    rows = true_feature_rows(plan, cache_plan)
+    span.set(rows=rows, bytes=rows * features.shape[1] * features.dtype.itemsize)
 
 
 class PlanProducer:
@@ -176,8 +185,10 @@ class PlanProducer:
         if self.num_replicas >= 1:
             return self._build_mesh(epoch, index, targets)
         obs = self.obs
-        with obs.span("plan/build", {"epoch": epoch, "batch": index}):
-            with obs.span("plan/sample") as sp_sample:
+        with obs.span("plan/build",
+                      {"epoch": epoch, "batch": index, "worker": current_worker()},
+                      cpu=True):
+            with obs.span("plan/sample", cpu=True) as sp_sample:
                 if self.mode in ("dp", "pushpull"):
                     samples = self.sampler.sample_micro_batch(
                         targets, self.num_devices, epoch, index
@@ -193,7 +204,7 @@ class PlanProducer:
                         )
                     else:
                         sample = self.sampler.sample_batch(targets, epoch, index)
-            with obs.span("plan/split") as sp_split:
+            with obs.span("plan/split", cpu=True) as sp_split:
                 if self.mode in ("dp", "pushpull"):
                     plan = build_dp_plan(
                         samples, pad_multiple=self.pad_multiple,
@@ -210,20 +221,19 @@ class PlanProducer:
                         with_halves=self.with_halves,
                         replication=self.replication,
                     )
-            with obs.span("plan/load") as sp_load:
+            with obs.span("plan/load", cpu=True) as sp_load:
                 cache_plan, feats, breakdown = stage_host_features(
                     plan, self.features, self.cache, self.serve_cache,
                     self.pad_multiple,
                 )
                 labels = load_labels(plan, self.labels)
+                if obs.enabled:
+                    _load_counters(sp_load, plan, cache_plan, self.features)
             if self.injector is not None:
                 feats = self.injector.maybe_poison("build", epoch, index, feats)
             # the producer end of the flow arrow that lands on the consumer
             # step training on this plan (keyed by the plan's (epoch, batch))
             obs.flow_start(("plan", epoch, index))
-        obs.observe("plan/sample_s", sp_sample.duration)
-        obs.observe("plan/split_s", sp_split.duration)
-        obs.observe("plan/load_s", sp_load.duration)
         return PlanBatch(
             index=index,
             epoch=epoch,
@@ -280,12 +290,15 @@ class PlanProducer:
         from repro.train.plan_io import load_labels, stage_host_features
 
         obs = self.obs
-        with obs.span("plan/build", {"epoch": epoch, "batch": index}):
-            with obs.span("plan/sample") as sp_sample:
+        with obs.span("plan/build",
+                      {"epoch": epoch, "batch": index, "worker": current_worker()},
+                      cpu=True):
+            with obs.span("plan/sample", cpu=True) as sp_sample:
                 samples = self._sample_replicas(epoch, index, targets)
             parts, t_split, t_load = [], 0.0, 0.0
             for replica, sample in enumerate(samples):
-                with obs.span("plan/split", {"replica": replica}) as sp_split:
+                with obs.span("plan/split", {"replica": replica},
+                              cpu=True) as sp_split:
                     if self.telemetry is not None:
                         self.telemetry.record(sample)
                     plan = build_split_plan(
@@ -296,12 +309,15 @@ class PlanProducer:
                         with_halves=self.with_halves,
                         replication=self.replication,
                     )
-                with obs.span("plan/load", {"replica": replica}) as sp_load:
+                with obs.span("plan/load", {"replica": replica},
+                              cpu=True) as sp_load:
                     cache_plan, feats, breakdown = stage_host_features(
                         plan, self.features, self.cache, self.serve_cache,
                         self.pad_multiple,
                     )
                     labels = load_labels(plan, self.labels)
+                    if obs.enabled:
+                        _load_counters(sp_load, plan, cache_plan, self.features)
                 if self.injector is not None:
                     # _take claims once, so at most one replica is poisoned
                     feats = self.injector.maybe_poison(
@@ -324,9 +340,6 @@ class PlanProducer:
                     )
                 )
             obs.flow_start(("plan", epoch, index))
-        obs.observe("plan/sample_s", sp_sample.duration)
-        obs.observe("plan/split_s", t_split)
-        obs.observe("plan/load_s", t_load)
         return MeshPlanBatch(
             index=index,
             epoch=epoch,
@@ -348,6 +361,17 @@ def finalize_cache_plan(cp: CachePlan, hwm: dict, n_l: int) -> CachePlan:
     hwm["CM"] = max(hwm.get("CM", 0), cp.max_miss)
     hwm["CS"] = max(hwm.get("CS", 0), cp.max_send)
     return cp.pad_to(n_l, hwm["CM"], hwm["CS"])
+
+
+def _repad_counters(span, parts: list) -> None:
+    """``plan/repad``'s ``rows`` (true rows of the staged feature blocks)
+    and ``rows_padded`` (their padded height, over devices and parts)."""
+    from repro.train.plan_io import true_feature_rows
+
+    span.set(
+        rows=sum(true_feature_rows(p.plan, p.cache_plan) for p in parts),
+        rows_padded=sum(p.feats.shape[0] * p.feats.shape[1] for p in parts),
+    )
 
 
 def _finalize_mesh(
@@ -394,9 +418,10 @@ def _finalize_mesh(
             part.labels = pad_axis(
                 part.labels, 1, part.plan.front_ids[0].shape[1]
             )
+        if obs.enabled:
+            _repad_counters(sp, batch.parts)
     note_hwm_growth(obs, before, hwm, f"epoch{batch.epoch}/batch{batch.index}")
     batch.t_split += sp.duration
-    obs.observe("plan/repad_s", sp.duration)
     batch.signature = mesh_signature(
         [(p.plan, p.cache_plan) for p in batch.parts], sig_extra
     )
@@ -444,9 +469,10 @@ def _finalize(
         batch.labels = pad_axis(
             batch.labels, 1, batch.plan.front_ids[0].shape[1]
         )
+        if obs.enabled:
+            _repad_counters(sp, [batch])
     note_hwm_growth(obs, before, hwm, f"epoch{batch.epoch}/batch{batch.index}")
     batch.t_split += sp.duration
-    obs.observe("plan/repad_s", sp.duration)
     batch.signature = plan_signature(batch.plan, batch.cache_plan, sig_extra)
     if sig_cache is not None:
         batch.sig_hit = sig_cache.record(batch.signature)

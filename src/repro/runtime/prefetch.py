@@ -54,6 +54,15 @@ log = logging.getLogger("repro.prefetch")
 
 _JOIN_TIMEOUT_S = 10.0
 
+# the spawn index of the producer worker running on this thread
+_worker = threading.local()
+
+
+def current_worker() -> int:
+    """The index of the prefetch worker on the calling thread (its spawn
+    order, as in its name ``plan-producer-<n>``); -1 off the pool."""
+    return getattr(_worker, "index", -1)
+
 
 @dataclass
 class PrefetchStats:
@@ -128,6 +137,7 @@ class OrderedPrefetcher:
     def _spawn_worker(self) -> None:
         t = threading.Thread(
             target=self._work,
+            args=(self._spawned,),
             name=f"plan-producer-{self._spawned}",
             daemon=True,
         )
@@ -155,7 +165,8 @@ class OrderedPrefetcher:
             attempt, self._retry.delay_s(attempt), err,
         )
 
-    def _work(self) -> None:
+    def _work(self, worker: int) -> None:
+        _worker.index = worker
         while not self._stop.is_set():
             self._tickets.acquire()
             if self._stop.is_set():

@@ -11,6 +11,7 @@ Two loading paths feed the jitted step:
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -127,6 +128,20 @@ def stage_batch(
         plan_to_device(plan, cache_plan, with_halves, num_replicated),
         jnp.asarray(labels, jnp.int32),
     )
+
+
+def transfer_counts(staged) -> dict:
+    """``bytes`` and ``arrays`` of a staged pytree's leaves: what one
+    ``stage_batch`` sent host -> device (the ``step/put`` counters)."""
+    leaves = jax.tree_util.tree_leaves(staged)
+    return {"bytes": sum(x.nbytes for x in leaves), "arrays": len(leaves)}
+
+
+def true_feature_rows(plan: SplitPlan, cache_plan: CachePlan | None = None) -> int:
+    """True (unpadded) rows of a batch's host feature block: the cache
+    misses when served, else the input frontier, summed over devices."""
+    mask = plan.node_mask[-1] if cache_plan is None else cache_plan.miss_mask
+    return int(mask.sum())
 
 
 def load_features(plan: SplitPlan, features: np.ndarray) -> np.ndarray:

@@ -61,6 +61,7 @@ from repro.train.plan_io import (
     plan_to_device,
     stage_batch,
     stage_host_features,
+    transfer_counts,
 )
 
 
@@ -468,8 +469,9 @@ class Trainer:
             def loss_fn(params, inputs, plan_arrays, labels):
                 logits = forward_fn(params, inputs, plan_arrays)
                 mask = plan_arrays["target_mask"]
-                loss = masked_softmax_xent(logits, labels, mask)
-                acc = masked_accuracy(logits, labels, mask)
+                with jax.named_scope("loss"):
+                    loss = masked_softmax_xent(logits, labels, mask)
+                    acc = masked_accuracy(logits, labels, mask)
                 return loss, acc
 
             if not skip_nonfinite:
@@ -479,7 +481,8 @@ class Trainer:
                     (loss, acc), grads = jax.value_and_grad(
                         loss_fn, has_aux=True
                     )(params, inputs, plan_arrays, labels)
-                    params, opt_state = opt.update(grads, opt_state, params)
+                    with jax.named_scope("optimizer"):
+                        params, opt_state = opt.update(grads, opt_state, params)
                     return params, opt_state, loss, acc
 
                 return step
@@ -492,9 +495,10 @@ class Trainer:
                 finite = jnp.isfinite(loss)
                 for leaf in jax.tree_util.tree_leaves(grads):
                     finite = finite & jnp.all(jnp.isfinite(leaf))
-                new_params, new_opt_state = opt.update(
-                    grads, opt_state, params
-                )
+                with jax.named_scope("optimizer"):
+                    new_params, new_opt_state = opt.update(
+                        grads, opt_state, params
+                    )
                 params = jax.tree_util.tree_map(
                     lambda new, old: jnp.where(finite, new, old),
                     new_params, params,
@@ -547,8 +551,9 @@ class Trainer:
             def loss_fn(params, inputs, plan_arrays, labels):
                 logits = forward_fn(params, inputs, plan_arrays)
                 mask = plan_arrays["target_mask"]
-                loss = masked_softmax_xent(logits, labels, mask)
-                acc = masked_accuracy(logits, labels, mask)
+                with jax.named_scope("loss"):
+                    loss = masked_softmax_xent(logits, labels, mask)
+                    acc = masked_accuracy(logits, labels, mask)
                 return loss, acc
 
             @jax.jit
@@ -568,16 +573,20 @@ class Trainer:
                 num = len(replicas)
                 grads = jax.tree_util.tree_map(lambda t: t / num, grads)
                 if not skip_nonfinite:
-                    params, opt_state = opt.update(grads, opt_state, params)
+                    with jax.named_scope("optimizer"):
+                        params, opt_state = opt.update(
+                            grads, opt_state, params
+                        )
                     return params, opt_state, loss_sum / num, acc_sum / num
                 # guard the *averaged* gradient: any replica's NaN/Inf
                 # poisons the mean, so one check covers all R branches
                 finite = jnp.isfinite(loss_sum)
                 for leaf in jax.tree_util.tree_leaves(grads):
                     finite = finite & jnp.all(jnp.isfinite(leaf))
-                new_params, new_opt_state = opt.update(
-                    grads, opt_state, params
-                )
+                with jax.named_scope("optimizer"):
+                    new_params, new_opt_state = opt.update(
+                        grads, opt_state, params
+                    )
                 params = jax.tree_util.tree_map(
                     lambda new, old: jnp.where(finite, new, old),
                     new_params, params,
@@ -737,7 +746,9 @@ class Trainer:
                 if entry[1] is not None:
                     entry[2] = pad_axis(entry[2], 1, self._pad_hwm["CM"])
 
-        with self.obs.span("step", {"wait_s": 0.0}) as step_sp:
+        with self.obs.span(
+            "step", {"wait_s": 0.0}, step_num=self.global_step
+        ) as step_sp:
             with self.obs.span("step/stage") as sp_stage:
                 cached = staged[0][1] is not None
                 replicas = []
@@ -796,7 +807,9 @@ class Trainer:
                 feats = pad_axis(feats, 1, self._pad_hwm["CM"])
             labels = load_labels(plan, self.ds.labels)
 
-        with self.obs.span("step", {"wait_s": 0.0}) as step_sp:
+        with self.obs.span(
+            "step", {"wait_s": 0.0}, step_num=self.global_step
+        ) as step_sp:
             with self.obs.span("step/stage") as sp_stage:
                 plan_arrays = self._attach_rep(
                     plan_to_device(
@@ -897,37 +910,49 @@ class Trainer:
         """
         cached = batch.parts[0].cache_plan is not None
         replicas = []
-        for part in batch.parts:
-            feats_d, plan_arrays, labels_d = stage_batch(
-                part.plan, part.feats, part.labels, part.cache_plan,
-                with_halves=self.cfg.shuffle_overlap,
-                num_replicated=self._num_replicated(),
-            )
-            plan_arrays = self._attach_rep(plan_arrays)
-            inputs = (self.cache_block, feats_d) if cached else feats_d
-            replicas.append((inputs, plan_arrays, labels_d))
+        with self.obs.span("step/put") as sp_put:
+            staged = [
+                stage_batch(
+                    part.plan, part.feats, part.labels, part.cache_plan,
+                    with_halves=self.cfg.shuffle_overlap,
+                    num_replicated=self._num_replicated(),
+                )
+                for part in batch.parts
+            ]
+            if self.obs.enabled:
+                sp_put.set(**transfer_counts(staged))
+            for feats_d, plan_arrays, labels_d in staged:
+                plan_arrays = self._attach_rep(plan_arrays)
+                inputs = (self.cache_block, feats_d) if cached else feats_d
+                replicas.append((inputs, plan_arrays, labels_d))
         fn = self._mesh_cached_step_fn if cached else self._mesh_step_fn
-        return self._dispatch_step(fn, tuple(replicas))
+        with self.obs.span("step/dispatch"):
+            return self._dispatch_step(fn, tuple(replicas))
 
     def _step_batch(self, batch: PlanBatch):
         """Stage a finalized batch to device and dispatch the jitted step.
         Returns the (still-async) ``(loss, acc, finite)`` device values."""
         if isinstance(batch, MeshPlanBatch):
             return self._step_mesh_batch(batch)
-        feats_d, plan_arrays, labels_d = stage_batch(
-            batch.plan, batch.feats, batch.labels, batch.cache_plan,
-            with_halves=self.cfg.shuffle_overlap,
-            num_replicated=self._num_replicated(),
-        )
-        plan_arrays = self._attach_rep(plan_arrays)
-        if batch.cache_plan is not None:
-            return self._dispatch_step(
-                self._cached_step_fn, (self.cache_block, feats_d),
-                plan_arrays, labels_d,
+        with self.obs.span("step/put") as sp_put:
+            staged = stage_batch(
+                batch.plan, batch.feats, batch.labels, batch.cache_plan,
+                with_halves=self.cfg.shuffle_overlap,
+                num_replicated=self._num_replicated(),
             )
-        return self._dispatch_step(
-            self._step_fn, feats_d, plan_arrays, labels_d
-        )
+            if self.obs.enabled:
+                sp_put.set(**transfer_counts(staged))
+            feats_d, plan_arrays, labels_d = staged
+            plan_arrays = self._attach_rep(plan_arrays)
+        with self.obs.span("step/dispatch"):
+            if batch.cache_plan is not None:
+                return self._dispatch_step(
+                    self._cached_step_fn, (self.cache_block, feats_d),
+                    plan_arrays, labels_d,
+                )
+            return self._dispatch_step(
+                self._step_fn, feats_d, plan_arrays, labels_d
+            )
 
     def _mesh_iter_stats(
         self, plans, breakdowns, loss, acc, t_sample, t_split, t_load,
@@ -982,7 +1007,6 @@ class Trainer:
         obs = self.obs
         if not obs.enabled:
             return
-        obs.observe("step/compute_s", st.t_compute)
         obs.count("wire/bytes", st.wire_bytes)
         obs.count("plan/loaded_rows", st.loaded_rows)
         obs.count("plan/shuffle_rows", st.shuffle_rows)
@@ -1061,7 +1085,8 @@ class Trainer:
                 if batch is None:
                     break
                 with self.obs.span(
-                    "step", {"epoch": batch.epoch, "batch": batch.index}
+                    "step", {"epoch": batch.epoch, "batch": batch.index},
+                    step_num=self.global_step,
                 ) as step_sp:
                     # close the flow arrow from this plan's producer span
                     self.obs.flow_end(("plan", batch.epoch, batch.index))

@@ -377,3 +377,155 @@ def test_epoch_stats_fields_survive_with_obs_off(ds):
         assert it.t_split > 0.0
         assert it.t_load > 0.0
         assert it.t_compute > 0.0
+
+
+# --------------------------------------------------------------------- #
+# the profiler's clock, counters where the work happens, named scopes
+# --------------------------------------------------------------------- #
+def _dp_trainer(ds, obs_trace, spec=None):
+    """A one-device dp trainer on pipelined plans (the benchmark cell's
+    shape at the tiny size)."""
+    cfg = TrainConfig(
+        mode="dp", num_devices=1, fanouts=(4, 4), batch_size=32,
+        plan_source="pipelined", pipeline_depth=2, plan_workers=2, seed=7,
+        obs_trace=obs_trace,
+    )
+    return Trainer(ds, spec or _spec(ds), cfg)
+
+
+def _ring_spans(tr, name):
+    return [
+        e for e in tr.obs.tracer.to_chrome()["traceEvents"]
+        if e["ph"] == "X" and e["name"] == name
+    ]
+
+
+def test_profiler_trace_carries_the_spans(ds, tmp_path):
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    tr = _dp_trainer(ds, obs_trace=True)
+    with jax.profiler.trace(str(tmp_path)):
+        tr.train_epoch(max_iters=3)
+    (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    names = ("plan/build", "plan/load", "step/wait", "step/put", "step/dispatch")
+    host = {n: [] for n in names}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name in host:
+                        host[ev.name].append(ev.duration_ns / 1e3)
+    for name in names:
+        ring = [e["dur"] for e in _ring_spans(tr, name)]
+        assert ring and len(host[name]) == len(ring), name
+        # sorted pairing is the matching with the least largest gap: each
+        # annotation is within 1 ms of its ring twin
+        gaps = [abs(a - b) for a, b in zip(sorted(host[name]), sorted(ring))]
+        assert max(gaps) < 1e3, (name, gaps)
+
+
+def test_null_obs_makes_no_annotation_or_cpu_call(ds, monkeypatch):
+    import time
+
+    import jax
+
+    def forbidden(*a, **k):
+        raise AssertionError("called with obs off")
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", forbidden)
+    monkeypatch.setattr(jax.profiler, "StepTraceAnnotation", forbidden)
+    monkeypatch.setattr(time, "thread_time", forbidden)
+    tr = _dp_trainer(ds, obs_trace=False)
+    assert tr.obs is NULL_OBS
+    assert len(tr.train_epoch(max_iters=2).iters) == 2
+    with NULL_OBS.span("x", cpu=True, step_num=3) as sp:
+        pass
+    assert sp.duration >= 0.0 and sp.attrs is None
+    # the same patch stops a live tracer: the off path really skipped it
+    with pytest.raises(AssertionError, match="obs off"):
+        with Tracer().span("x", cpu=True):
+            pass
+
+
+def test_span_counters(ds, monkeypatch):
+    import jax
+    import numpy as np
+
+    import repro.train.trainer as trainer_mod
+
+    tr = _dp_trainer(ds, obs_trace=True)
+    true_rows, staged = {}, []
+    build, stage_batch = tr.producer.build, trainer_mod.stage_batch
+
+    def keep_build(epoch, index, targets):
+        pb = build(epoch, index, targets)
+        true_rows[(epoch, index)] = int(pb.plan.node_mask[-1].sum())
+        return pb
+
+    def keep_stage(plan, feats, labels, *a, **k):
+        out = stage_batch(plan, feats, labels, *a, **k)
+        leaves = jax.tree_util.tree_leaves(out)
+        staged.append((feats.shape, sum(x.nbytes for x in leaves), len(leaves)))
+        return out
+
+    monkeypatch.setattr(tr.producer, "build", keep_build)
+    monkeypatch.setattr(trainer_mod, "stage_batch", keep_stage)
+    tr.train_epoch(max_iters=3)
+
+    F = ds.features.shape[1]
+    assert ds.features.dtype == np.float32
+    builds = _ring_spans(tr, "plan/build")
+    loads = _ring_spans(tr, "plan/load")
+    assert len(builds) == len(loads) == len(true_rows) > 0
+    for b in builds:
+        a = b["args"]
+        assert 0 < a["cpu_s"] <= b["dur"] / 1e6 + 5e-3
+        assert a["worker"] in (0, 1)
+        # the load span nested in this build, on the same thread
+        (load,) = [
+            e for e in loads if e["tid"] == b["tid"]
+            and b["ts"] <= e["ts"] and e["ts"] + e["dur"] <= b["ts"] + b["dur"]
+        ]
+        assert load["args"]["rows"] == true_rows[(a["epoch"], a["batch"])]
+        assert load["args"]["bytes"] == load["args"]["rows"] * F * 4
+    puts = _ring_spans(tr, "step/put")
+    assert [(p["args"]["bytes"], p["args"]["arrays"]) for p in puts] == [
+        (nbytes, n) for _, nbytes, n in staged
+    ]
+    repads = _ring_spans(tr, "plan/repad")
+    assert len(repads) == len(staged)
+    for r, (shape, _, _) in zip(repads, staged):
+        assert shape[0] == 1  # one device: the staged height is the block's
+        assert 0 < r["args"]["rows"] <= r["args"]["rows_padded"] == shape[1]
+
+
+@pytest.mark.parametrize(
+    "model,backend", [("sage", "jnp"), ("sage", "pallas"), ("gat", "jnp")]
+)
+def test_step_hlo_carries_named_scopes(ds, model, backend):
+    import re
+
+    from repro.train.plan_io import stage_batch
+
+    spec = GNNSpec(
+        model=model, in_dim=ds.spec.feat_dim, hidden_dim=16,
+        out_dim=ds.spec.num_classes, num_layers=2, num_heads=4,
+        agg_backend=backend,
+    )
+    tr = _dp_trainer(ds, obs_trace=False, spec=spec)
+    src = tr.plan_source_for(0, max_iters=1)
+    try:
+        batch = next(iter(src))
+    finally:
+        src.close()
+    lowered = tr._step_fn.lower(
+        tr.params, tr.opt_state,
+        *stage_batch(batch.plan, batch.feats, batch.labels),
+    )
+    op_names = re.findall(r'loc\("(jit\([^"]*)"', lowered.as_text(debug_info=True))
+    assert any("gnn/layer0" in n for n in op_names)
+    assert any(re.search(r"[/(]agg[/)]", n) for n in op_names)
+    assert any(re.search(r"[/(]optimizer[/)]", n) for n in op_names)
