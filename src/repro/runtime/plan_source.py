@@ -106,13 +106,16 @@ class MeshPlanBatch:
         return len(self.parts)
 
 
-def _load_counters(span, plan, cache_plan, features: np.ndarray) -> None:
-    """``plan/load``'s ``rows`` (the true rows gathered) and ``bytes`` (what
-    they read from the feature table)."""
+def _load_counters(span, plan, cache_plan, features: np.ndarray,
+                   reused: bool) -> None:
+    """``plan/load``'s ``rows`` (the true rows gathered), ``bytes`` (what
+    they read from the feature table) and ``reused`` (1 when the block they
+    were written into came from the pool, 0 when it was allocated)."""
     from repro.train.plan_io import true_feature_rows
 
     rows = true_feature_rows(plan, cache_plan)
-    span.set(rows=rows, bytes=rows * features.shape[1] * features.dtype.itemsize)
+    span.set(rows=rows, bytes=rows * features.shape[1] * features.dtype.itemsize,
+             reused=int(reused))
 
 
 class PlanProducer:
@@ -143,6 +146,7 @@ class PlanProducer:
         num_replicas: int = 0,  # 0 = 1D path; >=1 = (R, P) mesh fan-out
         obs: Obs = NULL_OBS,  # tracing/metrics sink (repro.obs)
         injector=None,  # repro.faults.FaultInjector | None (chaos hooks)
+        pool=None,  # train.plan_io.FeatureBlockPool | None (feature blocks)
     ):
         if mode not in ("split", "dp", "pushpull"):
             raise ValueError(f"unknown mode {mode!r}")
@@ -174,6 +178,10 @@ class PlanProducer:
         self.num_replicas = num_replicas
         self.obs = obs
         self.injector = injector
+        # the feature blocks are written into blocks from this pool; the
+        # consumer hands each back once its step has read it (None: a fresh
+        # array per batch)
+        self.pool = pool
 
     def build(self, epoch: int, index: int, targets: np.ndarray):
         from repro.train.plan_io import load_labels, stage_host_features
@@ -222,13 +230,14 @@ class PlanProducer:
                         replication=self.replication,
                     )
             with obs.span("plan/load", cpu=True) as sp_load:
-                cache_plan, feats, breakdown = stage_host_features(
+                cache_plan, feats, breakdown, reused = stage_host_features(
                     plan, self.features, self.cache, self.serve_cache,
-                    self.pad_multiple,
+                    self.pad_multiple, self.pool,
                 )
                 labels = load_labels(plan, self.labels)
                 if obs.enabled:
-                    _load_counters(sp_load, plan, cache_plan, self.features)
+                    _load_counters(sp_load, plan, cache_plan, self.features,
+                                   reused)
             if self.injector is not None:
                 feats = self.injector.maybe_poison("build", epoch, index, feats)
             # the producer end of the flow arrow that lands on the consumer
@@ -311,13 +320,14 @@ class PlanProducer:
                     )
                 with obs.span("plan/load", {"replica": replica},
                               cpu=True) as sp_load:
-                    cache_plan, feats, breakdown = stage_host_features(
+                    cache_plan, feats, breakdown, reused = stage_host_features(
                         plan, self.features, self.cache, self.serve_cache,
-                        self.pad_multiple,
+                        self.pad_multiple, self.pool,
                     )
                     labels = load_labels(plan, self.labels)
                     if obs.enabled:
-                        _load_counters(sp_load, plan, cache_plan, self.features)
+                        _load_counters(sp_load, plan, cache_plan,
+                                       self.features, reused)
                 if self.injector is not None:
                     # _take claims once, so at most one replica is poisoned
                     feats = self.injector.maybe_poison(
@@ -363,6 +373,19 @@ def finalize_cache_plan(cp: CachePlan, hwm: dict, n_l: int) -> CachePlan:
     return cp.pad_to(n_l, hwm["CM"], hwm["CS"])
 
 
+def _repad_blocks(part: PlanBatch, hwm: dict, pool) -> None:
+    """Pad a part's feature and label blocks to its repadded plan (and cache
+    plan); ``pool`` takes back a feature block that the repad replaces."""
+    from repro.train.plan_io import pad_block
+
+    if part.cache_plan is not None:
+        rows = hwm["CM"]
+    else:
+        rows = part.plan.front_ids[-1].shape[1]
+    part.feats = pad_block(part.feats, rows, pool)
+    part.labels = pad_axis(part.labels, 1, part.plan.front_ids[0].shape[1])
+
+
 def _repad_counters(span, parts: list) -> None:
     """``plan/repad``'s ``rows`` (true rows of the staged feature blocks)
     and ``rows_padded`` (their padded height, over devices and parts)."""
@@ -380,6 +403,7 @@ def _finalize_mesh(
     sig_cache: SignatureCache | None,
     sig_extra: tuple = (),
     obs: Obs = NULL_OBS,
+    pool=None,
 ) -> MeshPlanBatch:
     """Delivery-side finalize for a mesh batch: two repad passes over the R
     parts against the *shared* high-water marks.
@@ -409,15 +433,7 @@ def _finalize_mesh(
                         part.cache_plan, hwm, part.plan.front_ids[-1].shape[1]
                     )
         for part in batch.parts:
-            if part.cache_plan is not None:
-                part.feats = pad_axis(part.feats, 1, hwm["CM"])
-            else:
-                part.feats = pad_axis(
-                    part.feats, 1, part.plan.front_ids[-1].shape[1]
-                )
-            part.labels = pad_axis(
-                part.labels, 1, part.plan.front_ids[0].shape[1]
-            )
+            _repad_blocks(part, hwm, pool)
         if obs.enabled:
             _repad_counters(sp, batch.parts)
     note_hwm_growth(obs, before, hwm, f"epoch{batch.epoch}/batch{batch.index}")
@@ -437,6 +453,7 @@ def _finalize(
     sig_cache: SignatureCache | None,
     sig_extra: tuple = (),
     obs: Obs = NULL_OBS,
+    pool=None,
 ) -> PlanBatch:
     """Order-sensitive delivery step: repad to high-water marks, pad the
     staged feature/label blocks to match, and record the jit signature.
@@ -447,10 +464,11 @@ def _finalize(
     two-pass variant above. Observability rides the delivery point: the
     queue-dwell span (producer completion -> here), the repad span, any
     high-water-mark growth (a retrace warning — see ``note_hwm_growth``),
-    and the signature hit/miss counters.
+    and the signature hit/miss counters. ``pool`` takes back a pooled
+    feature block that the repad replaces with a larger copy.
     """
     if isinstance(batch, MeshPlanBatch):
-        return _finalize_mesh(batch, hwm, sig_cache, sig_extra, obs)
+        return _finalize_mesh(batch, hwm, sig_cache, sig_extra, obs, pool)
     if batch.t_built:
         obs.record("plan/queue_dwell", batch.t_built, time.perf_counter(),
                    {"epoch": batch.epoch, "batch": batch.index})
@@ -461,14 +479,7 @@ def _finalize(
             finalize_cache_plan(
                 batch.cache_plan, hwm, batch.plan.front_ids[-1].shape[1]
             )
-            batch.feats = pad_axis(batch.feats, 1, hwm["CM"])
-        else:
-            batch.feats = pad_axis(
-                batch.feats, 1, batch.plan.front_ids[-1].shape[1]
-            )
-        batch.labels = pad_axis(
-            batch.labels, 1, batch.plan.front_ids[0].shape[1]
-        )
+        _repad_blocks(batch, hwm, pool)
         if obs.enabled:
             _repad_counters(sp, [batch])
     note_hwm_growth(obs, before, hwm, f"epoch{batch.epoch}/batch{batch.index}")
@@ -527,6 +538,7 @@ class SerialPlanSource(PlanSource):
                 self.sig_cache,
                 self.sig_extra,
                 self.obs,
+                self.producer.pool,
             )
 
     def stats(self) -> dict:
@@ -574,7 +586,8 @@ class PipelinedPlanSource(PlanSource):
         try:
             for batch in self._prefetcher:
                 yield _finalize(
-                    batch, self.hwm, self.sig_cache, self.sig_extra, self.obs
+                    batch, self.hwm, self.sig_cache, self.sig_extra, self.obs,
+                    self.producer.pool,
                 )
         finally:
             self.close()

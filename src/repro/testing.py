@@ -1,4 +1,5 @@
-"""Property-test compatibility layer.
+"""Test support: the property-test compatibility layer and a feature-block
+pool that makes misuse visible (``PoisonedBlockPool``).
 
 The partitioner property suite (tests/test_partition_properties.py) is
 written against the ``hypothesis`` API. Environments without hypothesis —
@@ -12,6 +13,12 @@ Usage (drop-in for the hypothesis names used here):
 """
 from __future__ import annotations
 
+import threading
+
+import numpy as np
+
+from repro.train.plan_io import FeatureBlockPool
+
 try:  # pragma: no cover - exercised only where hypothesis is installed
     from hypothesis import given, settings, strategies as st  # noqa: F401
 
@@ -21,8 +28,6 @@ except ImportError:
 
     import functools
     import inspect
-
-    import numpy as np
 
     class _Ints:
         def __init__(self, lo: int, hi: int):
@@ -103,3 +108,39 @@ except ImportError:
             return wrapper
 
         return deco
+
+
+class PoisonedBlockPool(FeatureBlockPool):
+    """A ``FeatureBlockPool`` that makes misuse visible.
+
+    Its blocks are 64-byte aligned, which the CPU backend's ``jnp.asarray``
+    aliases rather than copies, and each block it takes back is filled with
+    NaN at once. A block released while a live device array still reads
+    it, or reused without every element rewritten, then shows as NaN in
+    whatever reads it. ``reused`` counts the acquires that reused a block.
+    """
+
+    ALIGN = 64
+
+    def __init__(self):
+        super().__init__()
+        self._count_lock = threading.Lock()
+        self.reused = 0
+
+    def acquire(self, shape, dtype):
+        block, reused = super().acquire(shape, dtype)
+        with self._count_lock:
+            self.reused += reused
+        return block, reused
+
+    def release(self, block):
+        taken = super().release(block)
+        if taken:
+            block.fill(np.nan)
+        return taken
+
+    def _allocate(self, shape, dtype):
+        n = int(np.prod(shape)) * dtype.itemsize
+        raw = np.empty(n + self.ALIGN, np.uint8)
+        off = -raw.ctypes.data % self.ALIGN
+        return raw[off:off + n].view(dtype).reshape(shape)

@@ -8,14 +8,22 @@ Two loading paths feed the jitted step:
     (``load_miss_features``); local/remote hits are assembled on device from
     the resident cache block (``core.shuffle.sim_serve_features``). The
     ``CachePlan`` arrays ride along in the plan pytree under ``"cache"``.
+
+Both gathers can write into a block from a ``FeatureBlockPool`` instead of
+a fresh array: a (P, N_L, F) float32 block is hundreds of MB at real
+widths, and a fresh one per batch pays for first-touching every page of it
+and unmapping it again.
 """
 from __future__ import annotations
+
+import threading
+import weakref
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.splitting import SplitPlan
+from repro.core.splitting import SplitPlan, pad_axis
 from repro.graph.cache import CachePlan
 
 
@@ -144,17 +152,79 @@ def true_feature_rows(plan: SplitPlan, cache_plan: CachePlan | None = None) -> i
     return int(mask.sum())
 
 
+class FeatureBlockPool:
+    """Free host feature blocks, kept for reuse and keyed by (shape, dtype).
+
+    ``acquire`` hands out a free block of the key, or a new one when the key
+    has none free: it never blocks and never caps. ``release`` takes back a
+    block that ``acquire`` handed out, once nothing reads it any more;
+    anything else (a padded copy, a second release) is ignored. A block
+    that is never released is simply garbage-collected: correctness never
+    depends on a release, only reuse does. A reused block holds the last
+    batch's rows, so its writer must overwrite every element.
+    """
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._free: dict[tuple, list[np.ndarray]] = {}
+        # blocks handed out and not yet released, by id; weak, so a block
+        # dropped without a release is freed (and leaves this map)
+        self._held = weakref.WeakValueDictionary()
+
+    def acquire(self, shape: tuple, dtype) -> tuple[np.ndarray, bool]:
+        """A block of ``shape`` and ``dtype``, and whether it was reused."""
+        key = (tuple(shape), np.dtype(dtype))
+        with self._lock:
+            free = self._free.get(key)
+            reused = bool(free)
+            block = free.pop() if reused else self._allocate(*key)
+            self._held[id(block)] = block
+        return block, reused
+
+    def _allocate(self, shape: tuple, dtype: np.dtype) -> np.ndarray:
+        return np.empty(shape, dtype)
+
+    def release(self, block: np.ndarray) -> bool:
+        """Take back a block ``acquire`` handed out; False (and nothing
+        kept) for any other array."""
+        with self._lock:
+            if self._held.get(id(block)) is not block:
+                return False
+            del self._held[id(block)]
+            self._free.setdefault((block.shape, block.dtype), []).append(block)
+        return True
+
+
+def _gather_rows(
+    features: np.ndarray, ids: np.ndarray, mask: np.ndarray,
+    out: np.ndarray | None,
+) -> np.ndarray:
+    """``features[ids]`` as float32 with the rows ``mask`` leaves out zeroed,
+    written into ``out`` (a new array when None). Every element of ``out``
+    is written, so a reused block keeps nothing of its last batch."""
+    if out is None:
+        out = np.empty((*ids.shape, features.shape[1]), np.float32)
+    if ids.size and (ids.min() < 0 or ids.max() >= len(features)):
+        raise IndexError(
+            f"feature ids outside [0, {len(features)}): "
+            f"{ids.min()}..{ids.max()}"
+        )
+    # mode="clip" after the check above: with ``out`` the default
+    # mode="raise" buffers the whole output, about 2.7x slower
+    np.take(features, ids, axis=0, out=out, mode="clip")
+    # zero only the padded rows (they gathered vertex 0's features) instead
+    # of multiplying the whole block by the mask — the padded fraction is
+    # small, so this roughly halves the memory traffic of the loading stage
+    out[~mask] = 0.0
+    return out
+
+
 def load_features(plan: SplitPlan, features: np.ndarray) -> np.ndarray:
     """The *loading* phase: gather input rows per device (dedup'd under split).
 
     Returns (P, N_L, F) float32; padding rows zeroed.
     """
-    rows = features[plan.front_ids[-1]].astype(np.float32, copy=False)
-    # zero only the padded rows (they gather vertex 0's features) instead of
-    # multiplying the whole block by the mask — the padded fraction is small,
-    # so this roughly halves the memory traffic of the loading stage
-    rows[~plan.node_mask[-1]] = 0.0
-    return rows
+    return _gather_rows(features, plan.front_ids[-1], plan.node_mask[-1], None)
 
 
 def load_miss_features(cp: CachePlan, features: np.ndarray) -> np.ndarray:
@@ -163,9 +233,7 @@ def load_miss_features(cp: CachePlan, features: np.ndarray) -> np.ndarray:
     This is the whole point of the serving path — the host link carries
     ``M`` rows per device instead of ``N_L``.
     """
-    rows = features[cp.miss_ids].astype(np.float32, copy=False)
-    rows[~cp.miss_mask] = 0.0
-    return rows
+    return _gather_rows(features, cp.miss_ids, cp.miss_mask, None)
 
 
 def stage_host_features(
@@ -174,19 +242,40 @@ def stage_host_features(
     cache=None,
     serve_cache: bool = False,
     pad_multiple: int = 8,
+    pool: FeatureBlockPool | None = None,
 ) -> tuple:
-    """The load stage for one plan: ``(cache_plan, feats, breakdown)``.
+    """The load stage for one plan: ``(cache_plan, feats, breakdown, reused)``.
 
     Chooses the serving path (compacted miss gather + CachePlan) or the full
     host gather. The single definition shared by ``PlanProducer.build``
     (producer threads) and ``Trainer.train_iter`` (inline path) — the two
-    must stay bit-identical.
+    must stay bit-identical. With a ``pool`` the rows are written into a
+    block acquired from it (``reused`` says whether the block was a free
+    one); whoever steps the batch releases ``feats`` once the device has
+    read it.
     """
     if cache is not None and serve_cache and cache.serves:
         cp = cache.build_plan(plan, pad_multiple=pad_multiple)
-        return cp, load_miss_features(cp, features), cp.breakdown()
-    feats = load_features(plan, features)
-    return None, feats, (cache.classify_plan(plan) if cache else None)
+        ids, mask, breakdown = cp.miss_ids, cp.miss_mask, cp.breakdown()
+    else:
+        cp = None
+        ids, mask = plan.front_ids[-1], plan.node_mask[-1]
+        breakdown = cache.classify_plan(plan) if cache else None
+    out, reused = None, False
+    if pool is not None:
+        out, reused = pool.acquire((*ids.shape, features.shape[1]), np.float32)
+    return cp, _gather_rows(features, ids, mask, out), breakdown, reused
+
+
+def pad_block(
+    block: np.ndarray, rows: int, pool: FeatureBlockPool | None
+) -> np.ndarray:
+    """A staged feature block grown to ``rows`` rows a device (``pad_axis``);
+    a pooled block that the grown copy replaces goes back to ``pool``."""
+    grown = pad_axis(block, 1, rows)
+    if grown is not block and pool is not None:
+        pool.release(block)
+    return grown
 
 
 def load_labels(plan: SplitPlan, labels: np.ndarray) -> np.ndarray:

@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.splitting import pad_axis, repad_plan
+from repro.core.splitting import repad_plan
 from repro.faults.retry import RetryPolicy
 from repro.core import (
     build_dp_plan,
@@ -57,7 +57,9 @@ from repro.train.checkpoint import (
 )
 from repro.train.loss import masked_softmax_xent, masked_accuracy
 from repro.train.plan_io import (
+    FeatureBlockPool,
     load_labels,
+    pad_block,
     plan_to_device,
     stage_batch,
     stage_host_features,
@@ -430,6 +432,11 @@ class Trainer:
                 from repro.sampler.engine import _sample_device
 
                 self.recompiles.register("sample_device", _sample_device)
+        # host feature blocks are written into reused blocks of this pool;
+        # each goes back only once the step that read it has synced: on the
+        # CPU backend ``jnp.asarray`` may alias a block rather than copy it
+        # (DESIGN.md §6)
+        self.feature_pool = FeatureBlockPool()
         self.producer = PlanProducer(
             self.sampler,
             dataset.features,
@@ -447,6 +454,7 @@ class Trainer:
             num_replicas=cfg.num_replicas,
             obs=self.obs,
             injector=injector,
+            pool=self.feature_pool,
         )
 
     # ------------------------------------------------------------------ #
@@ -726,10 +734,11 @@ class Trainer:
         with self.obs.span("plan/load") as sp_load:
             staged = []  # [plan, cache_plan, feats, labels, breakdown]
             for plan in plans:
-                cache_plan, feats, breakdown = stage_host_features(
+                cache_plan, feats, breakdown, _ = stage_host_features(
                     plan, self.ds.features, self.cache,
                     serve_cache=self.cache_block is not None,
                     pad_multiple=self.cfg.pad_multiple,
+                    pool=self.feature_pool,
                 )
                 labels = load_labels(plan, self.ds.labels)
                 staged.append([plan, cache_plan, feats, labels, breakdown])
@@ -744,7 +753,9 @@ class Trainer:
                         )
             for entry in staged:
                 if entry[1] is not None:
-                    entry[2] = pad_axis(entry[2], 1, self._pad_hwm["CM"])
+                    entry[2] = pad_block(
+                        entry[2], self._pad_hwm["CM"], self.feature_pool
+                    )
 
         with self.obs.span(
             "step", {"wait_s": 0.0}, step_num=self.global_step
@@ -772,6 +783,8 @@ class Trainer:
                 self.recompiles.step("train_iter")
             with self.obs.span("step/device") as sp_dev:
                 loss, acc, finite = self._sync_step(loss, acc, finite)
+            for entry in staged:
+                self.feature_pool.release(entry[2])
             step_sp.attrs.update(
                 stage_s=sp_stage.duration, device_s=sp_dev.duration
             )
@@ -793,10 +806,11 @@ class Trainer:
         plan, t_sample, t_split = self._plan_for(targets)
 
         with self.obs.span("plan/load") as sp_load:
-            cache_plan, feats, breakdown = stage_host_features(
+            cache_plan, feats, breakdown, _ = stage_host_features(
                 plan, self.ds.features, self.cache,
                 serve_cache=self.cache_block is not None,
                 pad_multiple=self.cfg.pad_multiple,
+                pool=self.feature_pool,
             )
             if cache_plan is not None:
                 # widths follow the same high-water marks as the plan itself
@@ -804,7 +818,9 @@ class Trainer:
                 finalize_cache_plan(
                     cache_plan, self._pad_hwm, plan.front_ids[-1].shape[1]
                 )
-                feats = pad_axis(feats, 1, self._pad_hwm["CM"])
+                feats = pad_block(
+                    feats, self._pad_hwm["CM"], self.feature_pool
+                )
             labels = load_labels(plan, self.ds.labels)
 
         with self.obs.span(
@@ -834,6 +850,7 @@ class Trainer:
             # pay two round-trips to the device
             with self.obs.span("step/device") as sp_dev:
                 loss, acc, finite = self._sync_step(loss, acc, finite)
+            self.feature_pool.release(feats)
             step_sp.attrs.update(
                 stage_s=sp_stage.duration, device_s=sp_dev.duration
             )
@@ -1098,6 +1115,12 @@ class Trainer:
                     # sync point
                     with self.obs.span("step/device") as sp_dev:
                         loss, acc, finite = self._sync_step(loss, acc, finite)
+                    # the step has read its inputs: hand its feature
+                    # blocks back to the pool
+                    parts = (batch.parts if isinstance(batch, MeshPlanBatch)
+                             else [batch])
+                    for part in parts:
+                        self.feature_pool.release(part.feats)
                     step_sp.attrs.update(
                         wait_s=sp_wait.duration,
                         stage_s=sp_stage.duration,

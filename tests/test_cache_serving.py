@@ -14,6 +14,7 @@ from repro.graph.cache import FeatureCache
 from repro.graph.datasets import make_dataset
 from repro.graph.sampling import sample_minibatch
 from repro.models.gnn import GNNSpec
+from repro.testing import PoisonedBlockPool
 from repro.train.plan_io import (
     cache_plan_to_device,
     load_features,
@@ -139,7 +140,7 @@ def test_trainer_serving_matches_accounting_only(setup):
         out_dim=ds.spec.num_classes, num_layers=2,
     )
 
-    def run(serve: bool):
+    def run(serve: bool, pool=None):
         cfg = TrainConfig(
             mode="split", num_devices=NDEV, fanouts=(4, 4), batch_size=32,
             presample_epochs=2, seed=7, cache_mode="partitioned",
@@ -147,6 +148,11 @@ def test_trainer_serving_matches_accounting_only(setup):
             cache_serve=serve, plan_source="pipelined",
         )
         tr = Trainer(ds, spec, cfg)
+        # the served side's miss blocks come from a pool that poisons each
+        # block it takes back (repro.testing); the plain side gathers fresh
+        tr.producer.pool = pool
+        if pool is not None:
+            tr.feature_pool = pool
         traj, totals = [], None
         for _ in range(2):
             st = tr.train_epoch(max_iters=3)
@@ -154,8 +160,10 @@ def test_trainer_serving_matches_accounting_only(setup):
             totals = st.totals()
         return traj, totals
 
-    served_traj, served_tot = run(True)
+    pool = PoisonedBlockPool()
+    served_traj, served_tot = run(True, pool)
     plain_traj, plain_tot = run(False)
+    assert pool.reused > 0
     assert served_traj == plain_traj
     # fully-cached partitioned placement: zero host rows on the serving path
     assert served_tot["load_host_miss"] == 0

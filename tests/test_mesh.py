@@ -29,6 +29,7 @@ from repro.core.shuffle import SimComm, sim_alltoall
 from repro.graph.datasets import make_dataset
 from repro.models.gnn import GNNSpec
 from repro.runtime import MeshPlanBatch, mesh_signature, plan_signature
+from repro.testing import PoisonedBlockPool
 from repro.train.trainer import TrainConfig, Trainer
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
@@ -57,8 +58,14 @@ def _cfg(num_replicas, **kw):
     return TrainConfig(**base)
 
 
-def _trajectory(ds, spec, cfg, epochs=2, iters=2):
+def _trajectory(ds, spec, cfg, epochs=2, iters=2, pool="default"):
+    """``pool``: the trainer's own by default; None gathers each part into
+    a fresh array; else the feature-block pool to install."""
     tr = Trainer(ds, spec, cfg)
+    if pool != "default":
+        tr.producer.pool = pool
+        if pool is not None:
+            tr.feature_pool = pool
     traj = []
     for _ in range(epochs):
         st = tr.train_epoch(max_iters=iters)
@@ -133,12 +140,16 @@ def test_mesh_pipelined_matches_serial(ds):
     """serial == pipelined extends to mesh deliveries (R=2): same keyed
     RNG, same shared-HWM repadding on the ordered side of the queue."""
     spec = _spec(ds)
-    _, serial = _trajectory(ds, spec, _cfg(2, plan_source="serial"))
+    _, serial = _trajectory(ds, spec, _cfg(2, plan_source="serial"),
+                            pool=None)
+    # pooled parts, NaN-poisoned on release (see tests/test_runtime.py)
+    pool = PoisonedBlockPool()
     _, pipelined = _trajectory(
         ds, spec, _cfg(2, plan_source="pipelined", pipeline_depth=3,
-                       plan_workers=2)
+                       plan_workers=2), pool=pool,
     )
     assert len(serial) == len(pipelined) > 0
+    assert pool.reused > 0
     assert serial == pipelined
 
 

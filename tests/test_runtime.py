@@ -9,6 +9,7 @@ import pytest
 from repro.graph.datasets import make_dataset
 from repro.models.gnn import GNNSpec
 from repro.runtime import OrderedPrefetcher, plan_signature
+from repro.testing import PoisonedBlockPool
 from repro.train.trainer import TrainConfig, Trainer
 
 
@@ -24,13 +25,19 @@ def _spec(ds):
     )
 
 
-def _trajectory(ds, mode, source, epochs=2, iters=3):
+def _trajectory(ds, mode, source, epochs=2, iters=3, pool="default"):
+    """``pool``: the trainer's own by default; None gathers each batch into
+    a fresh array; else the feature-block pool to install."""
     cfg = TrainConfig(
         mode=mode, num_devices=4, fanouts=(4, 4), batch_size=32,
         presample_epochs=2, plan_source=source, pipeline_depth=3,
         plan_workers=2, seed=7,
     )
     tr = Trainer(ds, _spec(ds), cfg)
+    if pool != "default":
+        tr.producer.pool = pool
+        if pool is not None:
+            tr.feature_pool = pool
     traj = []
     last = None
     for _ in range(epochs):
@@ -169,9 +176,15 @@ def test_prefetcher_close_midstream_joins_workers():
 # --------------------------------------------------------------------- #
 @pytest.mark.parametrize("mode", ["split", "dp", "pushpull"])
 def test_pipelined_matches_serial_trajectory(ds, mode):
-    _, serial, _ = _trajectory(ds, mode, "serial")
-    _, pipelined, _ = _trajectory(ds, mode, "pipelined")
+    # the serial side gathers into fresh arrays; the pipelined side reuses
+    # pooled blocks that are NaN-poisoned on release and alias their staged
+    # device arrays (PoisonedBlockPool): a block released too early, or a
+    # row left unwritten, would show in the losses
+    _, serial, _ = _trajectory(ds, mode, "serial", pool=None)
+    pool = PoisonedBlockPool()
+    _, pipelined, _ = _trajectory(ds, mode, "pipelined", pool=pool)
     assert len(serial) == len(pipelined) > 0
+    assert pool.reused > 0
     # exact float equality: same RNG keys, same padded shapes, same jit
     assert serial == pipelined
 
