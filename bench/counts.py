@@ -7,7 +7,8 @@ vertices ``n_src`` and sampled edges ``edges``. The counts are the same
 whatever implements a layer: a split plan's halo rows, data parallelism's
 redundant rows and a kernel's padded tiles do not count.
 
-Conventions:
+The layers are counted by the configuration's model, ``bench/models/<model>.py``
+(``step_flops``); the loss is counted here. Conventions:
 
 * A multiply-add is 2 operations; an add, compare, exp or divide is 1.
 * The backward pass of each operation costs twice its forward (one product
@@ -27,13 +28,11 @@ def block_sizes(block: dict) -> list[dict]:
     ]
 
 
-def _layers(cfg: dict, sizes: list[dict]):
-    """``(size, d_in, d_out, is_input, is_last)`` per GraphSAGE layer, input
-    layer first."""
+def layers(cfg: dict, sizes: list[dict]):
+    """``(size, d_in, d_out, is_input, is_last)`` per layer, input layer
+    first."""
     from bench.reference import layer_dims
 
-    if cfg["model"] != "sage":
-        raise ValueError(f"no counts for model {cfg['model']!r}")
     dims = layer_dims(cfg)
     L = len(dims)
     for j, (d_in, d_out) in enumerate(dims):
@@ -42,17 +41,9 @@ def _layers(cfg: dict, sizes: list[dict]):
 
 def step_flops(cfg: dict, sizes: list[dict]) -> float:
     """Operations of one training step (forward, loss and backward)."""
-    total = 0.0
-    for s, d_in, d_out, is_input, is_last in _layers(cfg, sizes):
-        n, e = s["n_dst"], s["edges"]
-        act = 0 if is_last else n * d_out  # ReLU
-        agg = e * d_in + n * d_in  # sum over edges, divide by count
-        mm = 2 * 2 * n * d_in * d_out  # h_self @ W_self, agg @ W_neigh
-        fwd = agg + mm + n * d_out + act
-        bwd = mm + n * d_out + act  # weight gradients, bias
-        if not is_input:
-            bwd += mm + agg  # input gradients
-        total += fwd + bwd
+    from bench import registry
+
+    total = registry.load("models", cfg["model"]).step_flops(cfg, sizes)
     n_t = sizes[0]["n_dst"]
     c = int(cfg["num_classes"])
     total += 3 * 5 * n_t * c  # log-softmax, pick and their gradient

@@ -2,10 +2,12 @@
 
 A cell is found by name: ``workloads/<cell>.json`` (mode, splits,
 aggregation backend, plan source, fan-outs, batch, the limits of its check)
-names its configuration ``configs/<config>.json`` (model and graph). The
-per-layer metrics a cell reports are the ``per_layer`` entries of
-``BENCHMARK.json`` that list it, each read by ``metrics/<metric>.py``. A
-later cell, configuration or metric is a new file and a new entry.
+names its configuration ``configs/<config>.json`` (model and graph), whose
+``model`` names ``models/<model>.py`` (the model's weights, plain reference
+layer and work count). The per-layer metrics a cell reports are the
+``per_layer`` entries of ``BENCHMARK.json`` that list it, each read by
+``metrics/<metric>.py`` (``registry``). A later cell, configuration, model
+or metric is a new file and a new entry.
 
 The run:
 
@@ -19,7 +21,8 @@ The run:
    shapes compiled already); every step ends in the trainer's
    ``device_get``. With
    ``trace`` the profiler records the window and the program's own spans
-   are on.
+   are on, and each step program the window runs is kept (``StepPrograms``)
+   so that its ops' named scopes can be read (``trace_reduce.op_paths``).
 3. the check: once the window is closed, its peak memory read and the
    program's state freed, the plain reference trains the same three blocks
    from the same weights, at the configuration's matmul precision
@@ -28,11 +31,19 @@ The run:
 The harness drives the program through a few of the ``Trainer``'s private
 attributes (``program_attr``): a program change that renames one stops the
 run, so it never measures something else.
+
+The step's optimized HLO text, which names each instruction's scope in its
+``op_name`` metadata, comes from the jitted step that ``_dispatch_step``
+runs (``_step_fn`` or ``_cached_step_fn``): lowered on the arguments of its
+first call in the run, then ``Lowered.compile().as_text()`` once the window
+has closed. The compile is the one the call made, so it comes from JAX's
+caches; on a TPU v5e with the persistent cache warm it loads in 0.2 s and
+its text is the cold compile's, ``op_name`` metadata included (chip run).
+An XLA dump flag would miss every program loaded from the cache.
 """
 from __future__ import annotations
 
 import gc
-import importlib.util
 import json
 import shutil
 import sys
@@ -83,13 +94,9 @@ def load_cell(name: str) -> tuple[dict, dict]:
 
 def load_metric(name: str):
     """The reader module ``metrics/<name>.py``."""
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_').replace('-', '_')}",
-        BENCH / "metrics" / f"{name}.py",
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    from bench import registry
+
+    return registry.load("metrics", name)
 
 
 def declared(cell_name: str, bench: dict) -> tuple[list, list]:
@@ -152,6 +159,36 @@ class BlockRecorder:
             if epoch == 0 and index < CHECK_STEPS:
                 self.blocks[index] = block
         return sample
+
+
+class StepPrograms:
+    """The step programs the trainer dispatches: each one lowered on the
+    arguments of its first call, and which of them ran while ``in_window``."""
+
+    def __init__(self, tr):
+        import jax
+
+        self.lowered, self.window, self.in_window = {}, set(), False
+        dispatch = program_attr(type(tr), "_dispatch_step").__get__(tr)
+
+        def record(fn, *args):
+            leaves, tree = jax.tree_util.tree_flatten((tr.params, tr.opt_state, args))
+            key = (id(fn), tree, tuple(map(jax.typeof, leaves)))
+            if key not in self.lowered:
+                self.lowered[key] = fn.lower(tr.params, tr.opt_state, *args)
+            if self.in_window:
+                self.window.add(key)
+            return dispatch(fn, *args)
+
+        set_program_attr(tr, "_dispatch_step", record)
+
+    def compiled(self) -> list[tuple[str, str]]:
+        """(module name, optimized HLO text) of each program that ran in the
+        window."""
+        from bench.trace_reduce import module_name
+
+        texts = [self.lowered[key].compile().as_text() for key in self.window]
+        return [(module_name(text), text) for text in texts]
 
 
 def make_trainer(cfg: dict, cell: dict, graph, trace: bool):
@@ -292,6 +329,7 @@ def run_cell(workload: str, cell: dict, cfg: dict, devices: list, peaks: dict,
     prog = first_steps(tr, cfg, params0)
     phase(f"first {CHECK_STEPS} steps", t)
     t = time.perf_counter()
+    programs = StepPrograms(tr) if trace else None
     warm_epoch = program_attr(tr, "_epoch")
     ep = tr.train_epoch()
     phase(f"warm-up (1 epoch, recompiles {int(ep.recompiles.get('misses', 0))})", t)
@@ -307,6 +345,8 @@ def run_cell(workload: str, cell: dict, cfg: dict, devices: list, peaks: dict,
     try:
         annotation = jax.profiler.TraceAnnotation(WINDOW_MARK)
         annotation.__enter__()
+        if programs is not None:
+            programs.in_window = True
         t0, c0 = time.perf_counter(), time.process_time()
         while True:
             # the window replays the warm-up epoch: its batches are padded
@@ -318,6 +358,8 @@ def run_cell(workload: str, cell: dict, cfg: dict, devices: list, peaks: dict,
             if time.perf_counter() - t0 >= seconds:
                 break
         t1, c1 = time.perf_counter(), time.process_time()
+        if programs is not None:
+            programs.in_window = False
         annotation.__exit__(None, None, None)
     finally:
         if profiler is not None:
@@ -350,9 +392,10 @@ def run_cell(workload: str, cell: dict, cfg: dict, devices: list, peaks: dict,
 
         ctx = window_context(
             tr, cfg, recorder, window_epochs, iters, t0, t1,
-            trace_reduce.load(trace_dir, (WINDOW_MARK,)), peaks,
+            trace_reduce.load(trace_dir, (WINDOW_MARK,)), peaks, programs.compiled(),
         )
         shutil.rmtree(trace_dir, ignore_errors=True)
+        log(f"trace: busy {ctx['busy_s']!r} s, of it with no scope {ctx['unscoped_s']!r} s")
         device["busy_s"] = ctx["busy_s"]
         device["window_s"] = ctx["trace_window_s"]
         for m in layer:
@@ -399,9 +442,11 @@ def device_peaks(kind: str) -> dict:
 
 
 def window_context(tr, cfg, recorder, window_epochs, iters, t0, t1,
-                   trace, peaks) -> dict:
+                   trace, peaks, programs) -> dict:
     """What the per-layer metric readers read: the window's spans, the
-    required work of its blocks, the reduced device trace and the peaks."""
+    required work of its blocks, the reduced device trace, the peaks, and
+    ``scope_seconds(scope)``: the window's device seconds of ops under a
+    named scope (``programs``: ``StepPrograms.compiled``)."""
     from bench import counts, trace_reduce
 
     chrome = tr.obs.tracer.to_chrome()
@@ -424,6 +469,8 @@ def window_context(tr, cfg, recorder, window_epochs, iters, t0, t1,
     busy = trace_reduce.busy_seconds(trace, w0, w1)
     ops = trace_reduce.op_seconds(trace, w0, w1)
     gaps = trace_reduce.idle_gaps(trace, w0, w1)[:10]
+    paths = trace_reduce.op_paths(
+        trace, [(name, trace_reduce.hlo_scopes(text)) for name, text in programs])
     return {
         "steps": len(iters), "window_s": t1 - t0,
         "spans": [s for s in spans if s["t1"] > t0 and s["t0"] < t1],
@@ -431,7 +478,9 @@ def window_context(tr, cfg, recorder, window_epochs, iters, t0, t1,
         "flops": [counts.step_flops(cfg, s) for s in sizes],
         "trace": trace, "trace_t0": w0, "trace_t1": w1,
         "busy_s": busy, "trace_window_s": (w1 - w0) / 1e9,
-        "peaks": peaks,
+        "peaks": peaks, "hlo": [text for _, text in programs],
+        "scope_seconds": lambda scope: trace_reduce.scope_seconds(trace, paths, w0, w1, scope),
+        "unscoped_s": trace_reduce.scope_seconds(trace, paths, w0, w1, None),
         "breakdown": {
             "device_ops": [[n, s] for n, s in
                            sorted(ops.items(), key=lambda kv: -kv[1])[:10]],

@@ -1,21 +1,20 @@
-"""Plain reference of a cell's training step: GraphSAGE (mean).
+"""Plain reference of a cell's training step, for any model.
 
 Straight ``jax.numpy`` over one sampled block's edge lists: no plan, no
-padding to the program's buckets, no split, shuffle or kernel. It follows
-the published layer equations:
-
-GraphSAGE, mean aggregator (Hamilton et al., arXiv:1706.02216, Alg. 1 with
-the concatenation written as two matrices):
-``h_v' = h_v W_self + mean_{u in N(v)} h_u W_neigh + b``.
+padding to the program's buckets, no split, shuffle or kernel. A model's
+layer equations, parameter layout and weights are in
+``bench/models/<model>.py`` (``init_params``, ``layer``), found by the
+configuration's ``model`` key; this file holds what every model shares.
 
 ReLU follows every layer but the last; the loss is the mean softmax cross
 entropy over the batch's targets. Adam (Kingma & Ba, Alg. 1) with the
 configuration's ``lr``, ``b1``, ``b2``, ``eps``.
 
-Weights are made here from the seed (``init_params``) and handed to the
-program, so the reference takes nothing the program made. Blocks are the
-sampled mini-batches in global vertex ids; ``block_arrays`` checks each one
-against the benchmark's own graph before the reference uses it.
+Weights are made by the model's module from the seed (``init_params``) and
+handed to the program, so the reference takes nothing the program made.
+Blocks are the sampled mini-batches in global vertex ids; ``block_arrays``
+checks each one against the benchmark's own graph before the reference uses
+it.
 
 The reference runs in float32 at the matmul precision that the
 configuration states (``matmul_precision``: on a TPU ``default`` is one
@@ -26,13 +25,12 @@ float32 master weights and optimizer.
 """
 from __future__ import annotations
 
-from functools import partial
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.graphgen import Graph, key_words
+from bench import registry
+from bench.graphgen import Graph
 
 
 def layer_dims(cfg: dict) -> list[tuple[int, int]]:
@@ -45,33 +43,15 @@ def layer_dims(cfg: dict) -> list[tuple[int, int]]:
     return dims
 
 
+def model(cfg: dict):
+    """The module ``bench/models/<model>.py`` of the configuration's model."""
+    return registry.load("models", cfg["model"])
+
+
 def init_params(cfg: dict, seed: int) -> list[dict]:
-    """Glorot-uniform weights and zero biases from ``seed``, made on the
-    device in one jitted call, in the layout the program takes."""
-    dims = tuple(layer_dims(cfg))
-    words = key_words(seed, 0x3C1)
-    if cfg["model"] != "sage":
-        raise ValueError(f"no reference for model {cfg['model']!r}")
-    return _init(words, dims)
-
-
-@partial(jax.jit, static_argnums=(1,))
-def _init(words, dims):
-    key = jax.random.wrap_key_data(words, impl="threefry2x32")
-
-    def glorot(k, shape):
-        lim = float(np.sqrt(6.0 / (shape[-2] + shape[-1])))
-        return jax.random.uniform(k, shape, jnp.float32, -lim, lim)
-
-    params = []
-    for d_in, d_out in dims:
-        key, k1, k2, _ = jax.random.split(key, 4)
-        params.append({
-            "w_self": glorot(k1, (d_in, d_out)),
-            "w_neigh": glorot(k2, (d_in, d_out)),
-            "b": jnp.zeros((d_out,), jnp.float32),
-        })
-    return params
+    """The model's weights from ``seed``, made on the device in one jitted
+    call, in the layout the program takes."""
+    return model(cfg).init_params(cfg, seed)
 
 
 # --------------------------------------------------------------------------- #
@@ -163,25 +143,22 @@ def _pad_blocks(arrs: list[dict]) -> tuple[list[dict], tuple]:
 # --------------------------------------------------------------------------- #
 # the step
 # --------------------------------------------------------------------------- #
-def forward(params, x, blk, sizes, dtype):
-    """Logits of the padded targets of one block."""
+def forward(params, x, blk, sizes, dtype, layer):
+    """Logits of the padded targets of one block, through the model's
+    ``layer``."""
     h = x.astype(dtype)
     L = len(params)
     for j, p in enumerate(params):
         k = L - 1 - j  # params[0] consumes the input features
         p = jax.tree_util.tree_map(lambda a: a.astype(dtype), p)
-        src, dst, self_idx, n = blk["src"][k], blk["dst"][k], blk["self"][k], sizes[k]
-        total = jax.ops.segment_sum(h[src], dst, n)
-        count = jax.ops.segment_sum(jnp.ones(dst.shape, dtype), dst, n)
-        agg = total / jnp.maximum(count, 1)[:, None]
-        h = h[self_idx] @ p["w_self"] + agg @ p["w_neigh"] + p["b"]
+        h = layer(p, h, blk["src"][k], blk["dst"][k], blk["self"][k], sizes[k], dtype)
         if j < L - 1:
             h = jax.nn.relu(h)
     return h
 
 
-def loss_fn(params, x, labels, mask, blk, sizes, dtype):
-    logits = forward(params, x, blk, sizes, dtype).astype(jnp.float32)
+def loss_fn(params, x, labels, mask, blk, sizes, dtype, layer):
+    logits = forward(params, x, blk, sizes, dtype, layer).astype(jnp.float32)
     logp = jax.nn.log_softmax(logits, axis=-1)
     nll = -jnp.take_along_axis(logp, labels[:, None], axis=-1)[:, 0]
     return jnp.sum(jnp.where(mask, nll, 0.0)) / jnp.sum(mask)
@@ -209,12 +186,13 @@ def run_steps(cfg: dict, params0, blocks: list[dict], graph: Graph, fanouts,
     arrs = [block_arrays(b, graph, fanouts, batch_size) for b in blocks]
     padded, sizes = _pad_blocks(arrs)
     cdt = jnp.dtype(dtype)
+    layer = model(cfg).layer
 
     @jax.jit
     def step(params, m, v, t, x, labels, mask, blk):
         with jax.default_matmul_precision(precision):
             loss, grads = jax.value_and_grad(
-                lambda p: loss_fn(p, x, labels, mask, blk, sizes, cdt)
+                lambda p: loss_fn(p, x, labels, mask, blk, sizes, cdt, layer)
             )(params)
             params, m, v = adam_update(cfg, params, grads, m, v, t)
         return params, m, v, loss, grads

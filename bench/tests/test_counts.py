@@ -33,5 +33,5 @@ def test_sage_step_flops_by_hand():
 
 
 def test_other_models_are_refused():
-    with pytest.raises(ValueError):
-        counts.step_flops(dict(SAGE, model="gat"), counts.block_sizes(BLOCK))
+    with pytest.raises(ValueError, match="bench/models/no_such_model.py"):
+        counts.step_flops(dict(SAGE, model="no_such_model"), counts.block_sizes(BLOCK))

@@ -40,8 +40,10 @@ def test_small_run_is_correct(name, trace):
     e2e, layer = harness.declared(name, bench)
     if trace:
         assert {"busy_s", "window_s"} <= set(result["device"])
-        # the CPU runs no TPU op: the device-trace readers find nothing
-        assert set(result["metrics"]) >= {"wait_share", "plan_build_ms", "stage_ms", "step_mfu"}
+        # every metric the cell declares from spans or the host's clock is
+        # read; the CPU runs no TPU op, so the device-trace readers may not be
+        assert set(result["metrics"]) >= {
+            m["name"] for m in layer if m["source"] in ("program_span", "host_clock")}
         assert set(result["metrics"]) <= {m["name"] for m in layer}
         assert set(result["breakdown"]) == {"device_ops", "idle_gaps"}
     else:

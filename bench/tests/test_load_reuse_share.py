@@ -44,12 +44,15 @@ def test_reader_finds_nothing():
     assert read(dict(WINDOW, spans=bare)) is None
 
 
-CELLS = [w["name"] for w in json.loads((ROOT / "BENCHMARK.json").read_text())["workloads"]]
+BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
+CELLS = [w["name"] for w in BENCH["workloads"]]
 
 
 @pytest.mark.parametrize("name", CELLS)
 def test_traced_small_run_reuses_the_blocks(name):
-    """The warm-up epoch fills the pool: the window's loads reuse blocks."""
+    """The warm-up epoch fills the pool: the window's loads reuse blocks, in
+    each cell that declares the metric."""
     result = small_run(name, 2**31 + 29, True)
     assert result["correct"], result["checks"]
-    assert result["metrics"]["load_reuse_share"]["value"] > 50
+    if "load_reuse_share" in {e["name"] for e in harness.declared(name, BENCH)[1]}:
+        assert result["metrics"]["load_reuse_share"]["value"] > 50

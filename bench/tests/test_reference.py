@@ -60,3 +60,19 @@ def test_control_runs_in_bfloat16(name):
     nums = correct.numbers(control, ref, params0)
     assert np.isfinite(list(nums.values())).all()
     assert not correct.judge(nums, cell["correct_limits"]), nums
+
+
+def test_run_steps_pinned():
+    """The reference's three steps on a small seeded CPU run of
+    ``sage-orkut.single`` give the losses that the reference gave before its
+    layer moved to ``bench/models/sage.py``, bit for bit."""
+    cell, cfg = small_cell("sage-orkut.single")
+    seed = 2**31 + 77
+    graph = graphgen.generate(cfg, 5)
+    blocks = sampled_blocks(cell, cfg, graph, seed)
+    params0 = reference.init_params(cfg, seed)
+    args = (cfg, params0, blocks, graph, cell["fanouts"], cell["batch_size"])
+    assert reference.run_steps(*args, precision="default")["losses"] == [
+        4.101616859436035, 3.5404157638549805, 3.4140634536743164]
+    assert reference.run_steps(*args, precision="default", dtype="bfloat16")["losses"] == [
+        4.096427917480469, 3.546271800994873, 3.413743257522583]

@@ -6,7 +6,7 @@ import re
 import pytest
 from conftest import ROOT
 
-from bench import harness
+from bench import counts, harness, reference
 
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -30,6 +30,28 @@ def test_config_found_by_name(entry):
     for key in entry["reduced"]:
         assert NAME.match(key) and key in cfg
         assert not key.endswith(("_dim", "_rank")) and "hidden" not in key
+
+
+@pytest.mark.parametrize("entry", BENCH["configs"], ids=lambda e: e["name"])
+def test_model_found_by_name(entry):
+    """The configuration's model brings its weights, plain reference layer
+    and work count in ``bench/models/<model>.py``."""
+    model = json.loads((ROOT / entry["file"]).read_text())["model"]
+    assert (ROOT / "bench" / "models" / f"{model}.py").is_file()
+    mod = reference.model({"model": model})
+    for name in ("init_params", "layer", "step_flops"):
+        assert callable(getattr(mod, name)), name
+
+
+def test_unknown_model_names_its_file():
+    cfg = {"model": "no_such_model", "num_layers": 1, "feat_dim": 2, "hidden_dim": 2,
+           "num_classes": 2}
+    for call in (lambda: reference.init_params(cfg, 1),
+                 lambda: counts.step_flops(cfg, [{"n_dst": 1, "n_src": 1, "edges": 1}])):
+        with pytest.raises(ValueError, match="bench/models/no_such_model.py"):
+            call()
+    with pytest.raises(ValueError, match="bench/metrics/no_such_metric.py"):
+        harness.load_metric("no_such_metric")
 
 
 @pytest.mark.parametrize("entry", BENCH["workloads"], ids=lambda e: e["name"])
