@@ -91,9 +91,12 @@ def init_gnn_params(key: jax.Array, spec: GNNSpec) -> list[dict]:
                 }
             )
         elif spec.model == "gat":
+            # hidden layers concatenate H heads of d_out / H features; the
+            # output layer averages H heads of d_out (``_gat_heads_out``)
             H = spec.num_heads
-            dh = d_out // H
-            assert dh * H == d_out, "gat: out dim must divide num_heads"
+            is_last = i == spec.num_layers - 1
+            dh = d_out if is_last else d_out // H
+            assert is_last or dh * H == d_out, "gat: hidden dim must divide num_heads"
             params.append(
                 {
                     "w": _glorot(k1, (d_in, H, dh), dtype),
@@ -160,6 +163,44 @@ def _agg_weighted_sum(
     )
 
 
+def _head_scores(wh: jnp.ndarray, a: jnp.ndarray) -> jnp.ndarray:
+    """GAT's per-head scores ``(..., H)``: each head's ``d_h`` features of
+    ``wh (..., H, d_h)`` dotted with its attention vector ``a (H, d_h)``.
+
+    An elementwise product and a sum, in the rows' own precision, not a
+    dot. At the TPU's default matmul precision a dot may round its operands
+    to bfloat16, and how XLA lowers a small batched dot depends on the
+    shapes it is compiled for; the softmax and the a_dst gradient (which
+    only the LeakyReLU's kink keeps from cancelling) magnify a difference
+    in that rounding. On a TPU v5e, with the scores as a dot, the first
+    gradient of the three-layer GAT at 4 x 256 read as far from its plain
+    reference (``bench/models/gat.py``, the same equations at other
+    shapes) as a bfloat16 computation does; as a product and a sum, ten
+    times closer.
+    """
+    return (wh * a).sum(axis=-1)
+
+
+def _gat_heads_out(layer_params: dict, agg: jnp.ndarray,
+                   h_self: jnp.ndarray) -> jnp.ndarray:
+    """GAT's aggregated heads ``agg (..., N, H*dh)`` into the layer's output,
+    before its activation.
+
+    The output layer (its bias one head wide, ``init_gnn_params``) averages
+    its heads (Veličković et al., eq. 6). A hidden layer concatenates them
+    and, where its input ``h_self (..., N, F_in)`` (each destination's own
+    input row) is as wide as its output, adds that row: the skip across
+    the intermediate attentive layer (§3.3), before the bias as in DGL.
+    With one head the mean and the concatenation are the same.
+    """
+    H, dh = layer_params["w"].shape[1:]
+    if layer_params["b"].shape[-1] != H * dh:
+        agg = agg.reshape(*agg.shape[:-1], H, dh).mean(axis=-2)
+    elif h_self.shape[-1] == H * dh:
+        agg = agg + h_self
+    return agg + layer_params["b"]
+
+
 def gnn_layer_apply(
     spec: GNNSpec,
     layer_params: dict,
@@ -189,19 +230,16 @@ def gnn_layer_apply(
         w = layer_params["w"]  # (F_in, H, dh)
         H, dh = w.shape[1], w.shape[2]
         wh = jnp.einsum("mf,fhd->mhd", mixed, w)  # (M, H, dh)
-        s_src = jnp.einsum("mhd,hd->mh", wh, layer_params["a_src"])  # (M, H)
-        # dst-order scores, computed once per layer: destinations are local
-        # rows (``self_pos``), so only N_i of the M mixed rows ever
-        # contribute an a_dst score. Scoring ``wh[self_pos]`` directly is
-        # bit-identical per row to the old full (M, H) score table and
-        # replaces the chained dependent gathers ``s_dst[self_pos][edge_dst]``
-        # with one (N_i, H) table and a single (E, H) gather.
-        s_dst_n = jnp.einsum(
-            "nhd,hd->nh", wh[self_pos], layer_params["a_dst"]
-        )  # (N_i, H)
-        logits = jax.nn.leaky_relu(
-            s_src[edge_src] + s_dst_n[edge_dst], negative_slope=0.2
-        )  # (E, H)
+        with jax.named_scope("attn_score"):
+            s_src = _head_scores(wh, layer_params["a_src"])  # (M, H)
+            # dst-order scores, computed once per layer: destinations are
+            # local rows (``self_pos``), so only N_i of the M mixed rows ever
+            # contribute an a_dst score: one (N_i, H) table and a single
+            # (E, H) gather
+            s_dst_n = _head_scores(wh[self_pos], layer_params["a_dst"])  # (N_i, H)
+            logits = jax.nn.leaky_relu(
+                s_src[edge_src] + s_dst_n[edge_dst], negative_slope=0.2
+            )  # (E, H)
         # softmax normalization stays on the (E, H) jnp path in both
         # backends: it is H/dh-times smaller than the feature traffic, and
         # keeping one implementation makes the backends agree on alpha
@@ -213,7 +251,7 @@ def gnn_layer_apply(
         agg = _agg_weighted_sum(
             spec, wh.reshape(wh.shape[0], H * dh), alpha, lp, num_out
         )
-        out = agg + layer_params["b"]
+        out = _gat_heads_out(layer_params, agg, mixed[self_pos])
     else:
         raise ValueError(spec.model)
     if not is_last:
@@ -373,7 +411,8 @@ def _gnn_layer_overlap(
 
         out = B(_finish)(loc, rem, lp_v, h)
     else:  # gat
-        s_src_loc = jnp.einsum("...nhd,hd->...nh", wh, layer_params["a_src"])
+        with jax.named_scope("attn_score"):
+            s_src_loc = _head_scores(wh, layer_params["a_src"])
         if S > 0:
             # eager score exchange: H columns per row vs H*dh for features —
             # the small price that lets every feature chunk aggregate
@@ -387,17 +426,17 @@ def _gnn_layer_overlap(
         if pay_rep is not None:
             # replicated rows sit past the recv region in the mixed source
             # space; their a_src scores are computed on device like local rows
-            s_rep = jnp.einsum("rhd,hd->rh", wh_rep, layer_params["a_src"])
+            with jax.named_scope("attn_score"):
+                s_rep = _head_scores(wh_rep, layer_params["a_src"])
             s_src_mix = comm.append_rows(s_src_mix, s_rep)
 
         def _alpha(ssrc, whd, l):
-            s_dst_n = jnp.einsum(
-                "nhd,hd->nh", whd[l["self_pos"]], layer_params["a_dst"]
-            )
-            logits = jax.nn.leaky_relu(
-                ssrc[l["edge_src"]] + s_dst_n[l["edge_dst"]],
-                negative_slope=0.2,
-            )
+            with jax.named_scope("attn_score"):
+                s_dst_n = _head_scores(whd[l["self_pos"]], layer_params["a_dst"])
+                logits = jax.nn.leaky_relu(
+                    ssrc[l["edge_src"]] + s_dst_n[l["edge_dst"]],
+                    negative_slope=0.2,
+                )
             with jax.named_scope("softmax"):
                 return segment_ops.edge_softmax(
                     logits, l["edge_dst"], l["edge_mask"], num_out
@@ -427,7 +466,8 @@ def _gnn_layer_overlap(
             rem = jnp.concatenate(parts, axis=-1)
         else:
             rem = _zeros_like_agg()
-        out = loc + rem + layer_params["b"]
+        h_self = B(lambda hh, l: hh[l["self_pos"]])(h, lp_v)
+        out = _gat_heads_out(layer_params, loc + rem, h_self)
     if not is_last:
         out = jax.nn.relu(out)
     return out

@@ -178,7 +178,9 @@ def modeled_wire_bytes(plan, spec: GNNSpec, wire_dtype: str) -> int:
     Per row, the payload width depends on the schedule: the blocking path
     ships raw activations (``d_in``); the overlapped GAT path ships the
     transformed rows plus the eagerly exchanged a_src scores
-    (``d_out + H`` — see ``_gnn_layer_overlap``). This is the §7 channel
+    (``d_out + H`` for a hidden layer, whose heads are concatenated;
+    ``H * d_out + H`` for the output layer, whose heads are averaged after
+    the aggregation — see ``_gnn_layer_overlap``). This is the §7 channel
     model: bytes are counted here, converted to seconds with testbed
     bandwidths by the benchmarks.
     """
@@ -189,7 +191,8 @@ def modeled_wire_bytes(plan, spec: GNNSpec, wire_dtype: str) -> int:
     for li, lp in enumerate(plan.layers):
         d_in, d_out = dims[L - 1 - li]
         if spec.model == "gat" and spec.overlap:
-            per_row = d_out + spec.num_heads
+            heads = d_out * spec.num_heads if li == 0 else d_out  # li 0: output
+            per_row = heads + spec.num_heads
         else:
             per_row = d_in
         total += lp.shuffle_rows() * per_row * size

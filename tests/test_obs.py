@@ -529,3 +529,6 @@ def test_step_hlo_carries_named_scopes(ds, model, backend):
     assert any("gnn/layer0" in n for n in op_names)
     assert any(re.search(r"[/(]agg[/)]", n) for n in op_names)
     assert any(re.search(r"[/(]optimizer[/)]", n) for n in op_names)
+    if model == "gat":  # the attention scores and the edge softmax
+        assert any(re.search(r"[/(]attn_score[/)]", n) for n in op_names)
+        assert any(re.search(r"[/(]softmax[/)]", n) for n in op_names)

@@ -267,6 +267,23 @@ def test_wire_bytes_model_halves_under_bf16(ds, part):
     assert b32 > 0 and b32 == 2 * b16
 
 
+def test_wire_bytes_gat_overlap_counts_the_exchanged_heads(ds, part):
+    """The overlapped GAT path ships each layer's transformed rows, all its
+    heads, plus H a_src scores: a hidden layer's heads are concatenated
+    (``d_out`` wide), the output layer's are averaged only after the
+    aggregation (``H * out_dim`` wide on the wire)."""
+    plan = _plan(ds, part)
+    spec = GNNSpec(model="gat", in_dim=ds.spec.feat_dim, hidden_dim=16,
+                   out_dim=4, num_layers=2, num_heads=2, overlap=True)
+    params = init_gnn_params(jax.random.PRNGKey(0), spec)
+    exchanged = [p["w"].shape[1] * p["w"].shape[2] + 2 for p in params]
+    assert exchanged == [16 + 2, 2 * 4 + 2]
+    rows = [lp.shuffle_rows() for lp in plan.layers]  # dst depth 0 = output
+    assert rows[0] > 0 and rows[1] > 0
+    assert modeled_wire_bytes(plan, spec, "float32") == 4 * (
+        rows[1] * exchanged[0] + rows[0] * exchanged[1])
+
+
 def test_signature_keys_on_overlap_knobs(ds, part):
     from repro.runtime.signature import plan_signature
 
